@@ -28,13 +28,15 @@ shared-VM container, occasionally to 1.10; a sub-floor delta carries no
 information.)
 
 Each op also carries an analytic device model (flops, HBM bytes, arithmetic
-intensity) and the v5e roofline placement computed from the same
-PEAK/HBM constants as benchmarks.roofline — this is the per-op half of the
-device-perf report; `benchmarks.run --device-report` merges it with the
-per-cell roofline rows."""
+intensity) and, on a chip, its roofline placement against that chip's
+published peaks (`benchmarks.roofline.DEVICE_PEAKS`, keyed by device_kind;
+none on a CPU) — this is the per-op half of the device-perf report;
+`benchmarks.run --device-report` merges it with the per-cell roofline
+rows."""
 
 from __future__ import annotations
 
+import functools
 import json
 import time
 
@@ -43,7 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import csv_line
-from benchmarks.roofline import HBM, PEAK
+from benchmarks.roofline import peaks
 from repro.kernels import ref
 
 
@@ -80,17 +82,30 @@ def timeit_pair(fn_a, fn_b, *, iters=30, reps=15):
             float(np.median(ratios)))
 
 
+@functools.lru_cache(maxsize=None)
+def _device_peaks() -> dict | None:
+    """Published peaks of the chip these timings run on (an unknown
+    accelerator kind is an error); None on a CPU, which has no entry: the
+    ref oracles timed there get no roofline placement."""
+    dev = jax.devices()[0]
+    return None if dev.platform == "cpu" else peaks(dev.device_kind)
+
+
 def _roofline(flops: float, hbm_bytes: float) -> dict:
-    """v5e single-chip placement for one op: analytic compute/memory times
-    against the same peak numbers roofline.py uses for the program-level
-    table, plus the compute fraction of the binding term."""
-    t_comp = flops / PEAK
-    t_mem = hbm_bytes / HBM
+    """Single-chip placement for one op on the running device: analytic
+    compute/memory times against the same published peaks roofline.py uses
+    for the program-level table, plus the compute fraction of the binding
+    term. Off a chip only the analytic counts are kept."""
+    model = {"flops": flops, "hbm_bytes": hbm_bytes,
+             "intensity_flops_per_byte": flops / hbm_bytes}
+    pk = _device_peaks()
+    if pk is None:
+        return model
+    t_comp = flops / pk["flops_bf16"]
+    t_mem = hbm_bytes / pk["hbm_bytes_s"]
     bound = max(t_comp, t_mem)
     return {
-        "flops": flops,
-        "hbm_bytes": hbm_bytes,
-        "intensity_flops_per_byte": flops / hbm_bytes,
+        **model,
         "t_compute_s": t_comp,
         "t_memory_s": t_mem,
         "bound": "compute" if t_comp >= t_mem else "memory",
@@ -180,14 +195,11 @@ def _bench_fused(rng) -> dict:
         ok = jnp.max(scores, axis=1) >= thr * dens[best]
         return jnp.where(ok, best, -1).astype(jnp.int32)
 
-    sup_flat = sup_v.reshape(-1, d)
-    w_mat = ref.assign_weight_matrix(sup_w)
-
-    def fused_assign(q, sup_flat, w_mat, dens):
-        return ref.assign_ref(q, sup_flat, w_mat, dens, k, thr)[0]
+    def fused_assign(q, sup_v, sup_w, dens):
+        return ref.assign_ref(q, sup_v, sup_w, dens, k, thr)[0]
 
     jf, ju = jax.jit(fused_assign), jax.jit(unfused_assign)
-    us_f, us_u, ratio = timeit_pair(lambda: jf(q, sup_flat, w_mat, dens),
+    us_f, us_u, ratio = timeit_pair(lambda: jf(q, sup_v, sup_w, dens),
                                     lambda: ju(q, sup_v, sup_w, dens),
                                     iters=3, reps=11)
     csv_line("kernel/assign_4kx32_unfused", us_u,
@@ -195,14 +207,13 @@ def _bench_fused(rng) -> dict:
     csv_line("kernel/assign_4kx32_fused", us_f,
              f"speedup={us_u / us_f:.2f}x")
     n_sup = n_clusters * a_cap
-    # epilogue is the per-cluster segment reduce (2 flops/support element),
-    # not the dense block-diagonal gemm the MXU kernel runs
+    # epilogue is the per-cluster segment reduce (2 flops/support element)
     out["assign"] = {
         "shape": [m, n_clusters, a_cap, d], "unfused_us": us_u,
         "fused_us": us_f, "speedup": us_u / us_f, "paired_ratio": ratio,
         "model": _roofline(
             flops=m * n_sup * (3 * d + 2) + 2.0 * m * n_sup,
-            hbm_bytes=4 * (m * d + n_sup * d + n_sup * n_clusters + m)),
+            hbm_bytes=4 * (m * d + n_sup * d + n_sup + m)),
     }
 
     # --- fused multi-iteration LID sweep -----------------------------------
@@ -280,11 +291,17 @@ def main(quick: bool = True):
     ]
     for wtext in warnings:
         csv_line("kernel/WARNING", 0, wtext)
+    dev = jax.devices()[0]
     with open("BENCH_kernels.json", "w") as f:
         json.dump({"version": 2,
-                   "backend": "ref (CPU container; Pallas on TPU)",
+                   "backend": "ref",
+                   "device": {"platform": dev.platform,
+                              "kind": dev.device_kind,
+                              "count": len(jax.devices())},
                    "warn_rel_noise_floor": warn_rel,
-                   "roofline_model": {"peak_flops": PEAK, "hbm_bytes_s": HBM},
+                   "roofline_model": (None if _device_peaks() is None else
+                                      {"device_kind": dev.device_kind,
+                                       **_device_peaks()}),
                    "fused_ops": fused,
                    "warnings": warnings}, f, indent=2)
 

@@ -1,10 +1,11 @@
 """Roofline analysis from the dry-run artifacts (EXPERIMENTS.md §Roofline).
 
-Three terms per (arch x shape), single-pod 16x16 = 256 chips (v5e):
+Three terms per (arch x shape), single-pod 16x16 = 256 chips (v5e), with
+the chip's published peaks from `DEVICE_PEAKS`:
 
-  compute    = HLO_FLOPs_global / (256 * 197e12)          [s]
-  memory     = HLO_bytes_global / (256 * 819e9)           [s]
-  collective = collective_bytes_per_chip / 50e9           [s]
+  compute    = HLO_FLOPs_global / (256 * flops_bf16)      [s]
+  memory     = HLO_bytes_global / (256 * hbm_bytes_s)     [s]
+  collective = collective_bytes_per_chip / ici_link_bytes_s [s]
 
 Sources: HLO_FLOPs/bytes come from the UNROLLED cost-probe lowering (XLA's
 cost analysis counts while bodies once; the probe has no loops). Collective
@@ -24,9 +25,26 @@ import json
 import os
 
 CHIPS = {"single": 256, "multi": 512}
-PEAK = 197e12
-HBM = 819e9
-LINK = 50e9
+
+# Published per-chip peaks, keyed by `jax.Device.device_kind`. Source: Google
+# Cloud TPU documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s chip-to-chip interconnect over 4 links (50 GB/s
+# per link).
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bytes_s": 819e9,
+                    "ici_link_bytes_s": 50e9},
+}
+# the chip the dry-run's production meshes are built for
+DRYRUN_KIND = "TPU v5 lite"
+
+
+def peaks(kind: str) -> dict:
+    """The published peaks of one chip of `kind`; an unknown kind is an
+    error, never a default."""
+    if kind not in DEVICE_PEAKS:
+        raise ValueError(f"no published peaks for device kind {kind!r}; "
+                         f"known: {sorted(DEVICE_PEAKS)}")
+    return DEVICE_PEAKS[kind]
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ART = os.path.join(REPO, "experiments", "dryrun")
@@ -105,6 +123,7 @@ def model_flops(arch: str, shape: str, kind: str) -> float:
 # ------------------------------------------------------------- the table ----
 def build_rows(mesh: str = "single") -> list[dict]:
     chips = CHIPS[mesh]
+    pk = peaks(DRYRUN_KIND)
     rows = []
     for path in sorted(glob.glob(os.path.join(ART, f"*__{mesh}.json"))):
         d = json.load(open(path))
@@ -124,9 +143,9 @@ def build_rows(mesh: str = "single") -> list[dict]:
         bytes_g = d.get("probe_bytes_global") or (
             d.get("bytes_per_device", 0.0) * chips)
         coll = d.get("collectives", {}).get("total_bytes", 0)
-        t_comp = flops_g / (chips * PEAK)
-        t_mem = bytes_g / (chips * HBM)
-        t_coll = coll / LINK
+        t_comp = flops_g / (chips * pk["flops_bf16"])
+        t_mem = bytes_g / (chips * pk["hbm_bytes_s"])
+        t_coll = coll / pk["ici_link_bytes_s"]
         mf = model_flops(arch, shape, kind)
         terms = {"compute": t_comp, "memory": t_mem, "collective": t_coll}
         dom = max(terms, key=terms.get)
@@ -146,8 +165,11 @@ def build_rows(mesh: str = "single") -> list[dict]:
 
 def to_markdown(rows: list[dict], mesh: str) -> str:
     chips = CHIPS[mesh]
-    out = [f"### Roofline — {mesh} pod ({chips} chips, v5e: 197 TF/s bf16, "
-           f"819 GB/s HBM, 50 GB/s link)",
+    pk = peaks(DRYRUN_KIND)
+    out = [f"### Roofline — {mesh} pod ({chips} chips, {DRYRUN_KIND}: "
+           f"{pk['flops_bf16'] / 1e12:.0f} TF/s bf16, "
+           f"{pk['hbm_bytes_s'] / 1e9:.0f} GB/s HBM, "
+           f"{pk['ici_link_bytes_s'] / 1e9:.0f} GB/s link)",
            "",
            "| cell | compute s | memory s | collective s | dominant | "
            "MODEL/HLO flops | roofline frac | HBM GB/dev |",

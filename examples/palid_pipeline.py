@@ -16,7 +16,7 @@ import numpy as np
 from repro.core.alid import ALIDConfig, EngineSpec
 from repro.core.engine import fit
 from repro.data import auto_lsh_params, make_blobs_with_noise
-from repro.distributed.context import MeshContext
+from repro.distributed.context import MeshContext, make_mesh
 from repro.utils import avg_f1_score
 
 
@@ -36,7 +36,7 @@ def main():
           f"clusters of ~{cluster_size}, rest noise")
 
     if args.devices > 1:
-        mesh = jax.make_mesh((args.devices,), ("data",))
+        mesh = make_mesh((args.devices,), ("data",))
         ctx = MeshContext(mesh=mesh, data_axes=("data",), model_axis="data")
         espec = EngineSpec(engine="mesh", mesh_ctx=ctx)
         mode = f"PALID x{args.devices}"

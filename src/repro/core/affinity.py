@@ -41,24 +41,6 @@ def affinity_matrix(v: jax.Array, k: float, p: float = 2.0,
     return a * (1.0 - jnp.eye(v.shape[0], dtype=a.dtype))
 
 
-def affinity_column(
-    v_beta: jax.Array,
-    beta_idx: jax.Array,
-    v_i: jax.Array,
-    i: jax.Array,
-    k: float,
-    p: float = 2.0,
-    backend: str = "auto",
-) -> jax.Array:
-    """A[beta, i]: affinity of one vertex v_i against the local range.
-
-    Zeroes the self entry (a_ii = 0) by comparing global indices, which also
-    handles duplicate occurrences defensively.
-    """
-    col = affinity_block(v_beta, v_i[None, :], k, p, backend)[:, 0]
-    return jnp.where(beta_idx == i, 0.0, col)
-
-
 @functools.partial(jax.jit, static_argnames=("sample", "target", "percentile",
                                              "backend"))
 def estimate_k(v: jax.Array, sample: int = 512, target: float = 0.95,

@@ -26,7 +26,8 @@ retrieval substrate lives:
                        a time inside jit (DESIGN.md §3);
   * MeshEngine       — the PALID map phase sharded over a device mesh, with
                        either a replicated store or (n_shards > 0) the
-                       ShardedStore placed one HBM slice per device;
+                       ShardedStore, one HBM slice per device between
+                       rounds and gathered whole for a round;
   * StreamedEngine   — the ALID outer loop lifted to HOST level over a
                        host-resident `StreamedStore`: one routed shard is
                        device_put at a time into a double-buffered slot, so
@@ -50,7 +51,6 @@ from typing import Optional, Protocol
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.alid import (ALIDConfig, Clustering, EngineSpec, SeedResult,
@@ -66,8 +66,8 @@ from repro.core.roi import estimate_roi
 from repro.core.source import (DataSource, as_source, strided_sample_indices)
 from repro.core.store import (build_store, build_store_streamed,
                               global_bucket_sizes)
-from repro.distributed.context import MeshContext, mesh_context
-from repro.distributed.shardings import logical_spec, store_specs
+from repro.distributed.context import MeshContext, make_mesh
+from repro.distributed.shardings import store_specs
 from repro.lsh.pstable import (bucket_sizes, build_lsh, hash_queries,
                                shard_bucket_windows_host)
 
@@ -123,8 +123,12 @@ def _map_round(points, active, tables, seeds, k, cfg: ALIDConfig):
 @functools.partial(jax.jit, static_argnames=("cfg", "ctx"))
 def _map_round_mesh(points, active, tables, seeds, k, cfg: ALIDConfig,
                     ctx: MeshContext):
-    """PALID map phase: seeds sharded over the data axes, dataset + LSH
-    tables replicated; every device runs its seed batch under vmap."""
+    """PALID map phase: seeds sharded over the data axes; every device runs
+    its seed batch under vmap against the whole dataset. `points` is the
+    replicated array (+ LSH `tables`) or the mesh-placed ShardedStore
+    (`tables=None`), whose per-device slices XLA gathers for the round: the
+    Pallas kernels inside cannot be partitioned by GSPMD, so the map phase
+    is a shard_map and each device holds the full store while it runs."""
     data = ctx.data_axes if len(ctx.data_axes) > 1 else ctx.data_axes[0]
 
     def shard_fn(pts, act, tab, seeds_local):
@@ -132,24 +136,13 @@ def _map_round_mesh(points, active, tables, seeds, k, cfg: ALIDConfig,
             lambda s: alid_from_seed(pts, act, tab, s, k, cfg))(seeds_local)
 
     rep = lambda leaf: P(*([None] * leaf.ndim))
-    return shard_map(
+    return jax.shard_map(
         shard_fn, mesh=ctx.mesh,
-        in_specs=(P(None, None), P(None),
+        in_specs=(jax.tree.map(rep, points), P(None),
                   jax.tree.map(rep, tables), P(data)),
         out_specs=P(data),
-        check_rep=False,
+        check_vma=False,
     )(points, active, tables, seeds)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _map_round_mesh_sharded(store, active, seeds, k, cfg: ALIDConfig):
-    """Map phase against the mesh-placed ShardedStore. No shard_map: the
-    store's leading S axis is device-placed (store_specs) and GSPMD
-    materializes one shard slice per fori_loop step of the streaming CIVS —
-    each device's HBM holds its dataset slice plus a single in-flight shard,
-    not a replica."""
-    return jax.vmap(
-        lambda s: alid_from_seed(store, active, None, s, k, cfg))(seeds)
 
 
 # ----------------------------------------------------------------- engines --
@@ -293,10 +286,21 @@ class ShardedEngine(_EngineBase):
 
 class MeshEngine(_EngineBase):
     """PALID over a device mesh (paper Alg. 3): the map phase shards the
-    seed batch over the data axes; n_shards > 0 additionally places the
-    ShardedStore one HBM slice per device. Straggler story as in the paper:
-    seeds are over-decomposed and every instance runs the same masked
-    iteration count, so devices stay in lockstep; a lost device's seed range
+    seed batch over the data axes.
+
+    Memory bound: with replicated data (n_shards = 0) every device holds the
+    whole dataset and its LSH tables. n_shards > 0 retrieves through the
+    ShardedStore and keeps it one slice per device BETWEEN rounds only: a
+    round gathers the whole store onto every device (`_map_round_mesh`), so
+    peak device memory is the full store, as with replicated data, and the
+    round pays the gather. Holding 1/n_data per device through a round needs
+    each shard fetched inside the shard_map, which needs collectives inside
+    ALID's data-dependent while loops; devices run those for different trip
+    counts, so that fetch does not exist yet.
+
+    Straggler story as in the paper: seeds are over-decomposed, the
+    instances on one device run in masked lockstep under vmap, and a round
+    ends with its slowest device's batch; a lost device's seed range
     is re-issued by the host driver on the next round (fit is restartable at
     round granularity)."""
 
@@ -308,7 +312,7 @@ class MeshEngine(_EngineBase):
     def build(self, points, cfg, rng):
         self._setup_k_from_points(points, cfg)
         if self.ctx is None:
-            mesh = jax.make_mesh((jax.device_count(),), ("data",))
+            mesh = make_mesh((jax.device_count(),), ("data",))
             self.ctx = MeshContext(mesh=mesh, data_axes=("data",),
                                    model_axis="data")
         n_data = self.ctx.n_data
@@ -331,19 +335,9 @@ class MeshEngine(_EngineBase):
             self._bsizes = bucket_sizes(self._tables)
 
     def run_round(self, active, seeds, seed_valid):
-        if self._store is not None:
-            # partition the seed batch over the data axes (the shard_map
-            # analogue for the GSPMD path): each device runs
-            # seeds_per_round/n_data instances against its store slice
-            with mesh_context(self.ctx):
-                seed_spec = logical_spec("seeds")
-            seeds = jax.device_put(
-                seeds, NamedSharding(self.ctx.mesh, seed_spec))
-            results = _map_round_mesh_sharded(self._store, active, seeds,
-                                              self.k, self._cfg)
-        else:
-            results = _map_round_mesh(self._points, active, self._tables,
-                                      seeds, self.k, self._cfg, self.ctx)
+        data = self._store if self._store is not None else self._points
+        results = _map_round_mesh(data, active, self._tables, seeds, self.k,
+                                  self._cfg, self.ctx)
         return self._reduce(results, seed_valid)
 
 

@@ -24,8 +24,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.affinity import affinity_column
-from repro.kernels import ops
+from repro.kernels import ops, ref
 
 
 class LIDState(NamedTuple):
@@ -91,11 +90,9 @@ def lid_solve(state: LIDState, k: jax.Array, max_iters: int = 200,
                               state._replace(converged=jnp.array(False)))
 
 
-@functools.partial(jax.jit, static_argnames=("max_iters", "tol", "p",
-                                             "backend"))
+@functools.partial(jax.jit, static_argnames=("max_iters", "tol", "p"))
 def lid_solve_unfused(state: LIDState, k: jax.Array, max_iters: int = 200,
-                      tol: float = 1e-5, p: float = 2.0,
-                      backend: str = "auto") -> LIDState:
+                      tol: float = 1e-5, p: float = 2.0) -> LIDState:
     """The pre-sweep reference loop: one XLA-dispatched iteration per
     while_loop step. Kept as the bit-parity oracle for `lid_solve`'s
     chunked sweeps (tests/test_lid_sweep.py) and as the unfused arm of the
@@ -105,7 +102,7 @@ def lid_solve_unfused(state: LIDState, k: jax.Array, max_iters: int = 200,
         return (~s.converged) & (s.n_iters < max_iters)
 
     def body(s: LIDState):
-        pi = jnp.sum(s.x * s.ax)
+        pi = ref.tree_sum(s.x * s.ax)
         r = jnp.where(s.beta_mask, s.ax - pi, 0.0)
         c1 = s.beta_mask & (r > tol)
         c2 = s.beta_mask & (r < -tol) & (s.x > 0.0)
@@ -123,8 +120,8 @@ def lid_solve_unfused(state: LIDState, k: jax.Array, max_iters: int = 200,
             eps = jnp.where(den < 0.0, jnp.minimum(-num / den, 1.0), 1.0)
             scale = eps * mu
 
-            col = affinity_column(s.v_beta, s.beta_idx, s.v_beta[i],
-                                  s.beta_idx[i], k, p, backend)
+            col = ref.affinity_column_ref(s.v_beta, i, k, p)
+            col = jnp.where(s.beta_idx == s.beta_idx[i], 0.0, col)
             col = jnp.where(s.beta_mask, col, 0.0)
 
             onehot = jnp.zeros_like(x).at[i].set(1.0)

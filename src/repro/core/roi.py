@@ -48,7 +48,8 @@ def estimate_roi(
     wsum = jnp.maximum(jnp.sum(w), 1e-12)
     w = w / wsum
 
-    center = w @ v_beta                                         # D = sum x_i v_i
+    with jax.default_matmul_precision("highest"):   # f32 on every backend
+        center = w @ v_beta                                     # D = sum x_i v_i
 
     # pi(x_hat) = w^T A w recomputed exactly over the support block (zero
     # diagonal): the inner A w is the fused masked matvec — off-support
@@ -56,7 +57,8 @@ def estimate_roi(
     # (cap, cap) block never materializes.
     aw = ops.affinity_matvec(v_beta, beta_idx, v_beta, beta_idx, w, k, p,
                              backend=backend)
-    pi = w @ aw
+    with jax.default_matmul_precision("highest"):
+        pi = w @ aw
     pi = jnp.maximum(pi, 1e-12)
 
     dist = ops.pairwise_distance(v_beta, center[None, :], p,

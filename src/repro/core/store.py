@@ -80,7 +80,9 @@ def _build_store_impl(points: jax.Array, params: LSHParams, rng: jax.Array,
     # are pure, so regenerating proj here matches build_lsh_sharded exactly
     # without threading the array through.
     proj, _ = make_projections(rng, params, d, jnp.float32)
-    score = points @ proj[0, 0]  # bf16 @ f32 promotes to f32, like hash_chunk
+    # f32 on every backend; bf16 @ f32 promotes to f32, like hash_chunk
+    with jax.default_matmul_precision("highest"):
+        score = points @ proj[0, 0]
     order = jnp.argsort(score).astype(jnp.int32)           # (n,)
 
     gidx = jnp.concatenate([order, jnp.full((pad,), -1, jnp.int32)])

@@ -77,6 +77,19 @@ def make_regime_dataset(
     return make_blobs_with_noise(n_clusters, a_star, n_noise, d=d, seed=seed)
 
 
+def sample_nn_distances(points: np.ndarray, sample: int = 512,
+                        seed: int = 0) -> np.ndarray:
+    """Nearest-neighbour distance of each of `sample` seeded rows of
+    `points`, within that sample (f64)."""
+    rng = np.random.default_rng(seed)
+    m = min(sample, points.shape[0])
+    idx = rng.choice(points.shape[0], size=m, replace=False)
+    s = points[idx].astype(np.float64)
+    d2 = ((s[:, None, :] - s[None, :, :]) ** 2).sum(-1)
+    np.fill_diagonal(d2, np.inf)
+    return np.sqrt(d2.min(axis=1))
+
+
 def auto_lsh_params(
     points: np.ndarray,
     n_tables: int = 4,
@@ -89,13 +102,7 @@ def auto_lsh_params(
     """Pick the p-stable segment length r from the data scale: r = seg_scale *
     median nearest-neighbour distance keeps intra-cluster collision probability
     high (paper tunes r by hand in Fig. 6; this is the automated equivalent)."""
-    rng = np.random.default_rng(seed)
-    m = min(sample, points.shape[0])
-    idx = rng.choice(points.shape[0], size=m, replace=False)
-    s = points[idx].astype(np.float64)
-    d2 = ((s[:, None, :] - s[None, :, :]) ** 2).sum(-1)
-    np.fill_diagonal(d2, np.inf)
-    nn = np.sqrt(d2.min(axis=1))
+    nn = sample_nn_distances(points, sample, seed)
     r = float(np.median(nn)) * seg_scale
     return LSHParams(n_tables=n_tables, n_projections=n_projections,
                      seg_len=max(r, 1e-6), probe=probe)

@@ -10,7 +10,19 @@ import contextlib
 from dataclasses import dataclass
 from typing import Optional
 
-from jax.sharding import Mesh
+import jax
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...],
+              devices=None) -> Mesh:
+    """`jax.make_mesh` with every axis `Auto`. Since jax 0.8 the default is
+    `Explicit`, under which an array sharded by one jit (the mesh map
+    phase) cannot be consumed by a jit that states no mesh (the claim
+    reducer's scatter); this code base shards by `NamedSharding`s and
+    `shard_map`, which is what `Auto` axes mean."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 @dataclass(frozen=True)

@@ -25,7 +25,8 @@ def _affinity_kernel(k_ref, q_ref, c_ref, o_ref):
     q2 = jnp.sum(q * q, axis=-1, keepdims=True)             # (bm, 1)
     c2 = jnp.sum(c * c, axis=-1, keepdims=True).T           # (1, bn)
     d2 = q2 + c2 - 2.0 * jax.lax.dot_general(
-        q, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        q, c, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
     dist = jnp.sqrt(jnp.maximum(d2, 0.0))
     o_ref[...] = jnp.exp(-k_scale * dist).astype(o_ref.dtype)
 

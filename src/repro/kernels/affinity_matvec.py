@@ -34,7 +34,8 @@ def _matvec_kernel(k_ref, q_ref, qi_ref, c_ref, ci_ref, w_ref, o_ref):
     q2 = jnp.sum(q * q, axis=-1, keepdims=True)               # (bm, 1)
     c2 = jnp.sum(c * c, axis=-1, keepdims=True).T             # (1, n)
     d2 = q2 + c2 - 2.0 * jax.lax.dot_general(
-        q, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        q, c, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
     a = jnp.exp(-k_scale * jnp.sqrt(jnp.maximum(d2, 0.0)))
     a = jnp.where(qi_ref[...] == ci_ref[...], 0.0, a)         # (bm,1)==(1,n)
     # the weights contraction is the ONE stage of this op whose bits reach

@@ -1,19 +1,16 @@
 """Pallas TPU kernel for fused batched cluster assignment — the serving hot
-path (`Clustering.predict` / `serve.ClusterService`): affinity of a query
+path (`Clustering.predict` / `serve.ClusterServer`): affinity of a query
 batch against every stored cluster support, the weighted per-cluster score,
 the argmax, and the density-threshold accept, in one pass.
 
-The per-cluster weighted reduction is phrased as ONE matmul against the
-block-diagonal (C*A, C) weight matrix (`ref.assign_weight_matrix`), so the
-whole score tensor is two MXU contractions: exp(-k*dist(q, sup_flat)) then
-scores = aff @ W. The argmax epilogue uses a broadcast-iota one-hot to read
-dens[best] without a gather (lane-axis gathers don't vectorize on the VPU).
-
-Tiling: grid (M/bm,); each program holds a (bm, d) query tile plus the full
-(C*A, d) support panel + (C*A, C) weights in VMEM. C*A is
-n_clusters x support capacity — tens of KiB for realistic serving tables; a
-model-zoo-scale C would need a second grid axis with a cross-block argmax
-carry, which this path does not have.
+Tiling: grid (M/bm, C). Each program holds a (bm, d) query tile and ONE
+cluster's (A, d) support tile plus its (1, A) weights, so VMEM is
+O(bm·A + A·d) whatever the number of clusters. The cluster axis is the
+inner, sequential grid axis: the running best score, its cluster id and
+that cluster's density live in VMEM scratch across it, and the last step
+applies the density-threshold accept and writes the labels. A strictly
+greater score replaces the carry, so ties keep the lowest cluster id —
+`jnp.argmax`'s rule, which the ref oracle uses.
 """
 
 from __future__ import annotations
@@ -23,36 +20,51 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_NT = (((1,), (1,)), ((), ()))       # contract the last dims: a @ b.T
 
 
-def _assign_kernel(k_ref, t_ref, q_ref, s_ref, w_ref, dn_ref,
-                   lab_ref, bs_ref):
+def _assign_kernel(k_ref, t_ref, dn_ref, q_ref, s_ref, w_ref, lab_ref, bs_ref,
+                   best_ref, arg_ref, dens_ref):
+    c = pl.program_id(1)
+
+    @pl.when(c == 0)
+    def _():
+        best_ref[...] = jnp.full(best_ref.shape, -jnp.inf, jnp.float32)
+        arg_ref[...] = jnp.zeros(arg_ref.shape, jnp.int32)
+        dens_ref[...] = jnp.zeros(dens_ref.shape, jnp.float32)
+
     q = q_ref[...].astype(jnp.float32)            # (bm, d)
-    s = s_ref[...].astype(jnp.float32)            # (CA, d)
-    k_scale = k_ref[0, 0]
-    q2 = jnp.sum(q * q, axis=-1, keepdims=True)
-    s2 = jnp.sum(s * s, axis=-1, keepdims=True).T
+    s = s_ref[...].astype(jnp.float32)            # (A, d)
+    q2 = jnp.sum(q * q, axis=-1, keepdims=True)                  # (bm, 1)
+    s2 = jax.lax.dot_general(
+        jnp.ones((1, s.shape[1]), jnp.float32), s * s, _NT,
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)                      # (1, A)
     d2 = q2 + s2 - 2.0 * jax.lax.dot_general(
-        q, s, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    aff = jnp.exp(-k_scale * jnp.sqrt(jnp.maximum(d2, 0.0)))  # (bm, CA)
-    scores = jax.lax.dot_general(
-        aff, w_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                   # (bm, C)
-    best = jnp.argmax(scores, axis=-1).astype(jnp.int32)      # (bm,)
-    bscore = jnp.max(scores, axis=-1)                         # (bm,)
-    col = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    onehot = col == best[:, None]
-    densb = jnp.sum(jnp.where(onehot, dn_ref[...], 0.0), axis=-1)
-    ok = bscore >= t_ref[0, 0] * densb
-    lab_ref[...] = jnp.where(ok, best, -1)[:, None]
-    bs_ref[...] = bscore[:, None]
+        q, s, _NT, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)                      # (bm, A)
+    aff = jnp.exp(-k_ref[0] * jnp.sqrt(jnp.maximum(d2, 0.0)))
+    score = jnp.sum(aff * w_ref[...], axis=-1, keepdims=True)    # (bm, 1)
+
+    better = score > best_ref[...]
+    best_ref[...] = jnp.where(better, score, best_ref[...])
+    arg_ref[...] = jnp.where(better, c, arg_ref[...])
+    dens_ref[...] = jnp.where(better, dn_ref[c], dens_ref[...])
+
+    @pl.when(c == pl.num_programs(1) - 1)
+    def _():
+        ok = best_ref[...] >= t_ref[0] * dens_ref[...]
+        lab_ref[...] = jnp.where(ok, arg_ref[...], -1)
+        bs_ref[...] = best_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret"))
 def assign_pallas(
     q: jax.Array,         # (m, d) queries
-    sup_flat: jax.Array,  # (C*A, d) flattened cluster supports
-    w_mat: jax.Array,     # (C*A, C) block-diagonal weights
+    sup_v: jax.Array,     # (C, A, d) cluster supports
+    sup_w: jax.Array,     # (C, A) support weights
     dens: jax.Array,      # (C,) cluster densities
     k_scale: jax.Array,
     threshold: jax.Array,
@@ -61,32 +73,37 @@ def assign_pallas(
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     m, d = q.shape
-    ca, n_clusters = w_mat.shape
+    n_clusters, a, _ = sup_v.shape
+    bm = min(bm, -(-m // 8) * 8)                 # a small batch pads to 8
     pm = (-m) % bm
     qp = jnp.pad(q, ((0, pm), (0, 0)))
-    k_arr = jnp.asarray(k_scale, jnp.float32).reshape(1, 1)
-    t_arr = jnp.asarray(threshold, jnp.float32).reshape(1, 1)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
 
     labels, bscore = pl.pallas_call(
         _assign_kernel,
-        grid=((m + pm) // bm,),
+        grid=((m + pm) // bm, n_clusters),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((bm, d), lambda i: (i, 0)),
-            pl.BlockSpec((ca, d), lambda i: (0, 0)),
-            pl.BlockSpec((ca, n_clusters), lambda i: (0, 0)),
-            pl.BlockSpec((1, n_clusters), lambda i: (0, 0)),
+            smem, smem, smem,
+            pl.BlockSpec((bm, d), lambda i, c: (i, 0)),
+            pl.BlockSpec((None, a, d), lambda i, c: (c, 0, 0)),
+            pl.BlockSpec((None, 1, a), lambda i, c: (c, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((bm, 1), lambda i: (i, 0)),
-            pl.BlockSpec((bm, 1), lambda i: (i, 0)),
+            pl.BlockSpec((bm, 1), lambda i, c: (i, 0)),
+            pl.BlockSpec((bm, 1), lambda i, c: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m + pm, 1), jnp.int32),
             jax.ShapeDtypeStruct((m + pm, 1), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((bm, 1), jnp.float32),
+                        pltpu.VMEM((bm, 1), jnp.int32),
+                        pltpu.VMEM((bm, 1), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(k_arr, t_arr, qp, sup_flat, w_mat,
-      dens.astype(jnp.float32).reshape(1, -1))
+    )(jnp.asarray(k_scale, jnp.float32).reshape(1),
+      jnp.asarray(threshold, jnp.float32).reshape(1),
+      dens.astype(jnp.float32),
+      qp, sup_v, sup_w.astype(jnp.float32).reshape(n_clusters, 1, a))
     return labels[:m, 0], bscore[:m, 0]

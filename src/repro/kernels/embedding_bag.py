@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
 
 from repro.kernels.segment_matmul import align_segments
 
@@ -88,7 +87,7 @@ def embedding_bag_pallas(
             scratch_shapes=[pltpu.VMEM((be, dim), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((n_row_blocks * bw, dim), jnp.float32),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(block_row, first, aidx.reshape(-1, be), alocal.reshape(-1, be), table)

@@ -21,6 +21,7 @@ def _lsh_kernel(x_ref, proj_ref, bias_ref, o_ref, *, n_tables: int, n_proj: int,
     x = x_ref[...].astype(jnp.float32)                    # (bn, d)
     w = proj_ref[...].astype(jnp.float32)                 # (L*m, d)
     z = jax.lax.dot_general(x, w, (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
     z = z + bias_ref[...].astype(jnp.float32)             # (bn, L*m)
     h = jnp.floor(z / seg_len).astype(jnp.int32)

@@ -7,10 +7,11 @@ Dispatch policy — every op takes `backend`:
 
   "auto"      resolve from the environment: REPRO_KERNEL_BACKEND if set,
               else interpret when REPRO_KERNEL_INTERPRET=1 (kernel test
-              suite / debugging), else "pallas" on TPU and "ref" elsewhere
-              (this container is CPU-only; the refs are also what the
-              multi-pod dry-run lowers — the roofline reads XLA HLO either
-              way).
+              suite / debugging), else "pallas" when JAX's default backend
+              is a TPU and "ref" on any other (the CPU test suite; the refs
+              are also what the multi-pod dry-run lowers). A caller that
+              must run the compiled kernels checks the resolved mode
+              itself: `chip_smoke.py` refuses anything but "pallas".
   "ref"       the pure-jnp oracles in `repro.kernels.ref`.
   "pallas"    the compiled Pallas TPU kernels.
   "interpret" the Pallas kernels in interpreter mode — same kernel code,
@@ -198,18 +199,17 @@ def assign_clusters(q: jax.Array, sup_v: jax.Array, sup_w: jax.Array,
     points at the origin, scored against every support (and mis-assigned if
     a cluster sits near the origin).
     """
-    n_clusters, a, d = sup_v.shape
-    sup_flat = jnp.asarray(sup_v, jnp.float32).reshape(n_clusters * a, d)
-    w_mat = _ref.assign_weight_matrix(jnp.asarray(sup_w, jnp.float32))
+    sup_v = jnp.asarray(sup_v, jnp.float32)
+    sup_w = jnp.asarray(sup_w, jnp.float32)
     dens = jnp.asarray(dens, jnp.float32)
     k_scale = jnp.asarray(k_scale, jnp.float32)
     threshold = jnp.asarray(threshold, jnp.float32)
     mode = resolve_backend(backend)
     if mode == "ref":
-        labels, score = _ref.assign_ref(q, sup_flat, w_mat, dens, k_scale,
+        labels, score = _ref.assign_ref(q, sup_v, sup_w, dens, k_scale,
                                         threshold)
     else:
-        labels, score = assign_pallas(q, sup_flat, w_mat, dens, k_scale,
+        labels, score = assign_pallas(q, sup_v, sup_w, dens, k_scale,
                                       threshold,
                                       interpret=(mode == "interpret"), **kw)
     if valid is not None:

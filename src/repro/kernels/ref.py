@@ -1,9 +1,10 @@
 """Pure-jnp oracles for every Pallas kernel in this package.
 
 These are the ground truth the kernels are validated against (shape/dtype
-sweeps in tests/test_kernels.py) AND the fallback implementation used when
-running off-TPU (this container is CPU-only; kernels execute in interpret
-mode only inside tests).
+sweeps in tests/test_kernels.py, interpret mode) and what `ops` runs for
+backend="ref": the resolved mode on a non-TPU backend such as the CPU test
+suite, and the plain reference a TPU run is compared with
+(`chip_smoke.py`'s reference phase fits the same data on both).
 """
 
 from __future__ import annotations
@@ -12,27 +13,36 @@ import jax
 import jax.numpy as jnp
 
 MASK_VALUE = -1e30
+# f32 contractions run at full f32 precision on every backend (XLA's TPU
+# default for an f32 matmul is a single bf16 pass)
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def tree_sum(p: jax.Array, keepdims: bool = False) -> jax.Array:
+    """Sum over the last axis with a FIXED binary-tree order, in f32.
+
+    XLA picks the association of a reduction per lowering context — a 1-D
+    sum, a padded row, a gemv and a vmapped batched gemm all add the same
+    terms in different orders (1-ulp drifts that broke ref-vs-interpret
+    engine parity). Spelling the tree out as explicit pairwise adds over a
+    zero-padded power-of-two width pins the dataflow: every backend,
+    batched or not, fused or not, computes bit-identical output, and extra
+    zero padding (a kernel's lane-aligned layout) only adds exact zeros in
+    the top levels. Cost is log2(n) vectorized adds — no MXU needed.
+    """
+    p = p.astype(jnp.float32)
+    n = p.shape[-1]
+    size = 1 << max(n - 1, 0).bit_length()
+    p = jnp.pad(p, [(0, 0)] * (p.ndim - 1) + [(0, size - n)])
+    while p.shape[-1] > 1:
+        half = p.shape[-1] // 2
+        p = p[..., :half] + p[..., half:]
+    return p if keepdims else p[..., 0]
 
 
 def tree_matvec(a: jax.Array, w: jax.Array) -> jax.Array:
-    """(m, n) @ (n,) with a FIXED binary-tree reduction order: (m,) f32.
-
-    XLA picks the reduction order of `a @ w` per lowering context — the same
-    contraction lowers to a gemv standalone but a batched gemm under vmap,
-    and the two associate the n-sum differently (a 1-ulp density drift that
-    broke ref-vs-interpret engine parity). Spelling the tree out as explicit
-    pairwise adds pins the dataflow: every backend, batched or not, fused or
-    not, computes bit-identical output. Cost is log2(n) vectorized adds on a
-    zero-padded pow2 width — VPU-friendly, no MXU needed for a matvec.
-    """
-    p = a.astype(jnp.float32) * w.astype(jnp.float32)[None, :]
-    n = p.shape[-1]
-    size = 1 << max(n - 1, 0).bit_length()
-    p = jnp.pad(p, ((0, 0), (0, size - n)))
-    while p.shape[-1] > 1:
-        half = p.shape[-1] // 2
-        p = p[:, :half] + p[:, half:]
-    return p[:, 0]
+    """(m, n) @ (n,) through `tree_sum`'s fixed order: (m,) f32."""
+    return tree_sum(a.astype(jnp.float32) * w.astype(jnp.float32)[None, :])
 
 
 # ---------------------------------------------------------------- affinity --
@@ -58,7 +68,7 @@ def pairwise_distance_ref(q: jax.Array, c: jax.Array,
     if p == 2.0:
         q2 = jnp.sum(q32 * q32, -1)[:, None]
         c2 = jnp.sum(c32 * c32, -1)[None, :]
-        d2 = q2 + c2 - 2.0 * (q32 @ c32.T)
+        d2 = q2 + c2 - 2.0 * jnp.matmul(q32, c32.T, precision=HIGHEST)
         return jnp.sqrt(jnp.maximum(d2, 0.0))
     diff = jnp.abs(q32[:, None, :] - c32[None, :, :])
     return jnp.power(jnp.sum(jnp.power(diff, p), axis=-1), 1.0 / p)
@@ -69,6 +79,28 @@ def affinity_ref(q: jax.Array, c: jax.Array, k_scale: jax.Array,
     """exp(-k * ||q_i - c_j||_p): (m, d), (n, d) -> (m, n). No diagonal logic."""
     dist = pairwise_distance_ref(q, c, p)
     return jnp.exp(-k_scale * dist).astype(q.dtype)
+
+
+def affinity_column_ref(v: jax.Array, i: jax.Array, k_scale: jax.Array,
+                        p: float = 2.0) -> jax.Array:
+    """exp(-k ||v_j - v_i||_p): row i of a block against the whole block,
+    (cap, d), () -> (cap,) f32 — the LID sweep's on-demand column (Eq.
+    13/14).
+
+    Same |v|^2 + |c|^2 - 2 v.c expansion as `pairwise_distance_ref`, but
+    every d-sum is a row reduction of the block (|v_i|^2 is entry i of the
+    row norms, the cross term an elementwise product reduced over d), not
+    a matvec or a 1-D sum: XLA's CPU backend associates those differently
+    per batching context, while a row reduction over d is the same loop
+    everywhere — so the Pallas sweep run in interpret mode stays
+    bit-identical to this oracle inside the vmapped engines."""
+    v32 = v.astype(jnp.float32)
+    c32 = v32[i]
+    if p != 2.0:
+        return affinity_ref(v32, c32[None, :], k_scale, p)[:, 0]
+    v2 = jnp.sum(v32 * v32, -1)
+    d2 = v2 + v2[i] - 2.0 * jnp.sum(v32 * c32[None, :], -1)
+    return jnp.exp(-k_scale * jnp.sqrt(jnp.maximum(d2, 0.0)))
 
 
 def affinity_matvec_ref(q: jax.Array, q_idx: jax.Array, c: jax.Array,
@@ -159,10 +191,11 @@ def lid_sweep_ref(v_beta: jax.Array, beta_idx: jax.Array,
     idx = jnp.asarray(beta_idx, jnp.int32)
     mask = jnp.asarray(beta_mask)
     k32 = jnp.asarray(k_scale, jnp.float32)
+    cap = v32.shape[0]
 
     def step(carry):
         t, x, ax, it, _ = carry
-        pi = jnp.sum(x * ax)
+        pi = tree_sum(x * ax)
         r = jnp.where(mask, ax - pi, 0.0)
         c1 = mask & (r > tol)
         c2 = mask & (r < -tol) & (x > 0.0)
@@ -179,7 +212,7 @@ def lid_sweep_ref(v_beta: jax.Array, beta_idx: jax.Array,
             den = mu * mu * (-2.0 * ax[i] + pi)   # mu^2 * pi(s_i - x), a_ii=0
             eps = jnp.where(den < 0.0, jnp.minimum(-num / den, 1.0), 1.0)
             scale = eps * mu
-            col = affinity_ref(v32, v32[i][None, :], k32, p)[:, 0]
+            col = affinity_column_ref(v32, i, k32, p)
             col = jnp.where(idx == idx[i], 0.0, col)
             col = jnp.where(mask, col, 0.0)
             onehot = jnp.zeros_like(x).at[i].set(1.0)
@@ -189,7 +222,16 @@ def lid_sweep_ref(v_beta: jax.Array, beta_idx: jax.Array,
                 def refresh(args):
                     x_new, ax_new = args
                     w = jnp.where(mask & (x_new > support_eps), x_new, 0.0)
-                    full = affinity_matvec_ref(v32, idx, v32, idx, w, k32, p)
+                    # evaluated on the slot axis padded to the kernel's
+                    # 128-lane layout: XLA's CPU dot associates the (cap,
+                    # cap) block differently per operand shape, and the pad
+                    # slots carry zero weight, so padding only pins the bits
+                    pad = (-cap) % 128
+                    vp = jnp.pad(v32, ((0, pad), (0, 0)))
+                    ip = jnp.pad(idx, (0, pad), constant_values=-1)
+                    full = affinity_matvec_ref(vp, ip, vp, ip,
+                                               jnp.pad(w, (0, pad)), k32,
+                                               p)[:cap]
                     return jnp.where(mask, full, 0.0)
                 hit = (it + 1) % refresh_every == 0
                 ax_new = jax.lax.cond(hit, refresh, lambda a: a[1],
@@ -210,49 +252,30 @@ def lid_sweep_ref(v_beta: jax.Array, beta_idx: jax.Array,
     return x, ax, it, cv
 
 
-def assign_weight_matrix(sup_w: jax.Array) -> jax.Array:
-    """(C, A) per-cluster support weights -> (C*A, C) block-diagonal matrix
-    W[c*A + a, c] = w[c, a], so the weighted per-cluster score reduction
-    becomes ONE matmul: scores = affinity(q, sup_flat) @ W. Shared by the
-    ref oracle and the Pallas wrapper so both run the identical contraction."""
-    n_clusters, a = sup_w.shape
-    flat = sup_w.reshape(-1).astype(jnp.float32)
-    rows = jnp.arange(n_clusters * a)
-    return jnp.zeros((n_clusters * a, n_clusters), jnp.float32
-                     ).at[rows, rows // a].set(flat)
-
-
-def assign_ref(q: jax.Array, sup_flat: jax.Array, w_mat: jax.Array,
+def assign_ref(q: jax.Array, sup_v: jax.Array, sup_w: jax.Array,
                dens: jax.Array, k_scale: jax.Array,
                threshold: jax.Array, bm: int = 512
                ) -> tuple[jax.Array, jax.Array]:
-    """Fused batched cluster assignment (Clustering.predict / ClusterService):
+    """Fused batched cluster assignment (Clustering.predict / ClusterServer):
     affinity against every cluster support + weighted score + argmax +
     density-threshold accept, one pass.
 
-    q:(m,d), sup_flat:(C*A,d), w_mat:(C*A,C) (see `assign_weight_matrix`),
-    dens:(C,), threshold:() -> (labels (m,) int32 with -1 = no cluster,
-    best_score (m,) f32).
+    q:(m,d), sup_v:(C,A,d), sup_w:(C,A), dens:(C,), threshold:() ->
+    (labels (m,) int32 with -1 = no cluster, best_score (m,) f32).
 
-    Two CPU-side perf choices, both verified bitwise-neutral vs the naive
-    flat form on the benchmark shapes:
-      - the block-diagonal `w_mat` contraction collapses to a per-cluster
-        segment reduce (einsum over the A axis) — the dense (C*A, C) gemm
-        is free on the MXU but 32x redundant flops on the ref path;
-      - queries process in `bm`-row chunks mirroring the Pallas grid, so
-        the (bm, C*A) affinity block stays cache-resident instead of a
-        whole (m, C*A) round-trip (measured ~2x on m=4096, C*A=2048).
+    The per-cluster weighted score is a segment reduce (einsum over the A
+    axis), and queries process in `bm`-row chunks mirroring the Pallas
+    grid, so the (bm, C*A) affinity block stays cache-resident instead of a
+    whole (m, C*A) round-trip.
     """
-    n_clusters = w_mat.shape[1]
-    a_cap = w_mat.shape[0] // n_clusters
-    # recover the (C, A) weights from the block-diagonal matrix
-    sup_w = jnp.einsum(
-        "cac->ca", w_mat.reshape(n_clusters, a_cap, n_clusters))
+    n_clusters, a_cap, d = sup_v.shape
+    sup_flat = sup_v.reshape(n_clusters * a_cap, d)
 
     def block(qb):
         aff = affinity_ref(qb, sup_flat, k_scale).astype(jnp.float32)
         scores = jnp.einsum(
-            "mca,ca->mc", aff.reshape(-1, n_clusters, a_cap), sup_w)
+            "mca,ca->mc", aff.reshape(-1, n_clusters, a_cap), sup_w,
+            precision=HIGHEST)
         best = jnp.argmax(scores, axis=-1).astype(jnp.int32)
         bscore = jnp.max(scores, axis=-1)
         ok = bscore >= threshold * dens[best]
@@ -444,7 +467,8 @@ def lsh_hash_ref(x: jax.Array, proj: jax.Array, bias: jax.Array,
                  seg_len: float) -> jax.Array:
     """x:(n,d), proj:(L,m,d), bias:(L,m) -> int32 keys (n, L) (the kernels
     produce int32; callers bitcast to uint32)."""
-    z = jnp.einsum("nd,lmd->nlm", x.astype(jnp.float32), proj.astype(jnp.float32))
+    z = jnp.einsum("nd,lmd->nlm", x.astype(jnp.float32),
+                   proj.astype(jnp.float32), precision=HIGHEST)
     z = z + bias[None].astype(jnp.float32)
     h = jnp.floor(z / seg_len).astype(jnp.int32)
     acc = jnp.full(h.shape[:-1], jnp.uint32(0x811C9DC5))

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import compiler_params
-
 
 def _segment_kernel(row_block_ref, first_ref, seg_local_ref, msg_ref, o_ref,
                     *, bw: int, be: int):
@@ -105,7 +103,7 @@ def segment_matmul_pallas(
             out_specs=pl.BlockSpec((bw, d), lambda i, br, fr: (br[i], 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((n_row_blocks * bw, d), jnp.float32),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(block_row, first, alocal, amsg)

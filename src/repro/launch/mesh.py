@@ -8,15 +8,13 @@ leading "pod" axis over DCN. Axis conventions in DESIGN.md §4.
 
 from __future__ import annotations
 
-import jax
-
-from repro.distributed.context import MeshContext
+from repro.distributed.context import MeshContext, make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_context(*, multi_pod: bool = False, fsdp: bool = True) -> MeshContext:
@@ -28,11 +26,6 @@ def make_context(*, multi_pod: bool = False, fsdp: bool = True) -> MeshContext:
 
 def make_small_context(n_data: int = 4, n_model: int = 2) -> MeshContext:
     """Reduced mesh for subprocess tests (8 host devices)."""
-    mesh = jax.make_mesh((n_data, n_model), ("data", "model"))
+    mesh = make_mesh((n_data, n_model), ("data", "model"))
     return MeshContext(mesh=mesh, data_axes=("data",), model_axis="model")
 
-
-# v5e hardware constants for the roofline (per chip)
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # B/s
-ICI_BW_PER_LINK = 50e9          # B/s per link (~ per-direction)

@@ -25,12 +25,15 @@ import argparse
 import time
 
 import jax
+import numpy as np
 
 from repro.core.alid import ALIDConfig, EngineSpec
 from repro.core.engine import fit, make_engine
 from repro.core.source import make_source, strided_sample_indices
 from repro.data import auto_lsh_params, make_blobs_with_noise
-from repro.distributed.context import MeshContext
+from repro.data.synthetic import sample_nn_distances
+from repro.distributed.context import MeshContext, make_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.utils import avg_f1_score
 
 
@@ -59,7 +62,7 @@ def engine_spec(engine: str, devices: int, shards: int, chunk_size: int,
         else:
             engine = "replicated"
     if engine == "mesh":
-        mesh = jax.make_mesh((max(devices, 1),), ("data",))
+        mesh = make_mesh((max(devices, 1),), ("data",))
         ctx = MeshContext(mesh=mesh, data_axes=("data",), model_axis="data")
         return EngineSpec(engine="mesh", n_shards=shards, mesh_ctx=ctx,
                           chunk_size=chunk_size, backend=backend,
@@ -79,7 +82,29 @@ def engine_spec(engine: str, devices: int, shards: int, chunk_size: int,
                       backend=backend, dtype=dtype)
 
 
+def synthetic_deployment(n: int, d: int, clusters: int, seed: int = 0):
+    """The synthetic workload this launcher fits: `clusters` planted
+    Gaussian blobs holding 40% of the n points, the rest uniform noise
+    (`make_blobs_with_noise`), with LSH parameters calibrated on it and the
+    support capacity sized to one planted cluster plus slack.
+    Returns (SyntheticSpec, LSHParams, a_cap).
+
+    The LSH segment length is 8x the 10th percentile of sampled
+    nearest-neighbour distances, not `auto_lsh_params`' median: the
+    percentile has to fall among the planted points' distances, and with
+    this generator's 60% uniform noise at d = 128 the median is the noise
+    scale (~12x the intra-cluster distance), which puts a third of the data
+    in every bucket so CIVS retrieves near-random candidates."""
+    cluster_size = max(4, int(n * 0.4) // clusters)
+    spec = make_blobs_with_noise(clusters, cluster_size,
+                                 n - clusters * cluster_size, d=d, seed=seed)
+    seg_len = 8.0 * float(np.percentile(sample_nn_distances(spec.points), 10))
+    return (spec, auto_lsh_params(spec.points)._replace(seg_len=seg_len),
+            max(64, cluster_size + 32))
+
+
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=20000)
     ap.add_argument("--d", type=int, default=32)
@@ -200,13 +225,10 @@ def main():
         a_cap = args.a_cap or 128
         n, d = source.n, source.dim
     else:
-        cluster_size = max(4, int(args.n * 0.4) // args.clusters)
-        noise = args.n - args.clusters * cluster_size
-        spec = make_blobs_with_noise(args.clusters, cluster_size, noise,
-                                     d=args.d, seed=0)
+        spec, lshp, a_cap = synthetic_deployment(args.n, args.d,
+                                                 args.clusters)
         source = spec.points
-        lshp = auto_lsh_params(spec.points)
-        a_cap = args.a_cap or max(64, cluster_size + 32)
+        a_cap = args.a_cap or a_cap
         n, d = spec.points.shape
 
     cfg = ALIDConfig(a_cap=a_cap, delta=128, lsh=lshp,
@@ -272,7 +294,6 @@ def _chaos_demo(clean, source, cfg, args) -> None:
     Prints one greppable line — the CI chaos step asserts on it."""
     import os
 
-    import numpy as np
 
     from repro.core.resilience import (FaultySource, PipelineFaults,
                                        RetryPolicy)
@@ -324,7 +345,6 @@ def _chaos_demo(clean, source, cfg, args) -> None:
 def _serve_bench(res, source, rate_hz: float) -> None:
     """Open-loop traffic against the continuous-batching assignment server,
     replaying rows of the just-fitted dataset as queries."""
-    import numpy as np
 
     from repro.core.source import as_source
     from repro.serve import ClusterServer, run_open_loop
@@ -354,7 +374,6 @@ def _online_demo(res, source, cfg) -> None:
     restore the pre-insert label array BIT-IDENTICALLY from the
     checkpoint/manager.py snapshot, with the tenant hot-swapping versions
     while submits keep flowing."""
-    import numpy as np
 
     from repro.core.online import OnlineClustering
     from repro.core.source import as_source

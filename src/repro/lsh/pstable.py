@@ -157,7 +157,8 @@ def hash_queries(q: jax.Array, proj: jax.Array, bias: jax.Array,
     keys = hash_points(q, proj, bias, seg_len, backend)              # (L, Q)
     # analysis: allow(private-matmul): duplicate salt projection documented above — fusing it into the hash kernel would force a (Q, L, m) HBM round-trip
     z = (jnp.einsum("nd,lmd->lnm", q.astype(jnp.float32),
-                    proj.astype(jnp.float32))
+                    proj.astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST)
          + bias[:, None, :].astype(jnp.float32))
     bits = jax.lax.bitcast_convert_type(z, jnp.uint32)
     salts = _mix_fold(jax.lax.bitcast_convert_type(bits, jnp.int32))
@@ -212,7 +213,8 @@ def hash_chunk(chunk: jax.Array, proj: jax.Array, bias: jax.Array,
     shards by. Only O(chunk) rows are ever device-resident.
     """
     keys = hash_points(chunk, proj, bias, seg_len, backend)
-    score = chunk @ proj[0, 0]
+    with jax.default_matmul_precision("highest"):   # f32 on every backend
+        score = chunk @ proj[0, 0]
     return keys, score
 
 

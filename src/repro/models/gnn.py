@@ -97,7 +97,6 @@ def sharded_message_pass(h, edge_fn, src, dst, valid, n_nodes, aggregator,
         msg, e_out = edge_fn(h[src], h[dst], edge_feat)
         return _aggregate(msg, dst, n_nodes, aggregator, valid), e_out
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     flat = axes if len(axes) > 1 else axes[0]
 
@@ -120,11 +119,11 @@ def sharded_message_pass(h, edge_fn, src, dst, valid, n_nodes, aggregator,
 
     ef = edge_feat if edge_feat is not None else jnp.zeros(
         (src.shape[0], 1), h.dtype)
-    agg, e_out = shard_map(
+    agg, e_out = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(flat, None), P(flat), P(flat), P(flat), P(flat, None)),
         out_specs=(P(flat, None), P(flat, None)),
-        check_rep=False,
+        check_vma=False,
     )(h, src, dst, valid, ef)
     return agg, e_out
 

@@ -170,12 +170,11 @@ def moe_apply(params: dict, cfg: MoEConfig, x: jax.Array):
             aux = jax.lax.pmean(aux, ctx.data_axes + (ctx.model_axis,))
             return o.reshape(bb, ss, dd), aux
 
-        from jax.experimental.shard_map import shard_map
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             shard_fn, mesh=ctx.mesh,
             in_specs=(ep_specs, tok_spec),
             out_specs=(tok_spec, P()),
-            check_rep=False,
+            check_vma=False,
         )(ep_params, x)
         aux = jnp.mean(aux)
 

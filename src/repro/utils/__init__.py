@@ -1,6 +1,7 @@
 from repro.utils.metrics import (  # noqa: F401
     avg_f1_score,
+    best_f1_per_cluster,
     canonical_labels,
-    f1_contingency,
     label_agreement,
+    matched_agreement,
 )

@@ -66,13 +66,14 @@ def test_mini_dryrun_small_mesh():
         import jax, jax.numpy as jnp, functools
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_arch
-        from repro.distributed.context import MeshContext, mesh_context
+        from repro.distributed.context import (MeshContext, make_mesh,
+                                               mesh_context)
         from repro.distributed import shardings as shd
         from repro.models import transformer as lm_m
         from repro.train import steps as steps_lib
         from repro.train.optimizers import OptConfig, init_opt_state
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         ctx = MeshContext(mesh=mesh, data_axes=("data",), model_axis="model")
         for arch in ["gemma2-27b", "kimi-k2-1t-a32b"]:
             cfg = get_arch(arch).SMOKE_CONFIG
@@ -106,11 +107,12 @@ def test_mini_dryrun_runs_real_arrays():
     out = run_subprocess("""
         import jax, jax.numpy as jnp
         from repro.configs import get_arch
-        from repro.distributed.context import MeshContext, mesh_context
+        from repro.distributed.context import (MeshContext, make_mesh,
+                                               mesh_context)
         from repro.train import steps as S
         from repro.train.optimizers import OptConfig
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = MeshContext(mesh=mesh, data_axes=("data",), model_axis="model")
         cfg = get_arch("kimi-k2-1t-a32b").SMOKE_CONFIG
         opt = OptConfig(lr=1e-3)

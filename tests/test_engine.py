@@ -15,7 +15,7 @@ from repro.core.alid import (ALIDConfig, Clustering, EngineSpec,
 from repro.core.engine import fit, make_engine, resolve_claims
 from repro.core.palid import detect_clusters_parallel
 from repro.data import auto_lsh_params, make_blobs_with_noise
-from repro.distributed.context import MeshContext
+from repro.distributed.context import MeshContext, make_mesh
 from repro.utils import avg_f1_score, canonical_labels as canonical
 
 
@@ -214,7 +214,7 @@ def test_detect_clusters_shims_warn_and_match(blobs, cfg, reference):
 
 def test_detect_clusters_parallel_shim_and_k_deprecation(blobs, cfg,
                                                          reference):
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     ctx = MeshContext(mesh=mesh, data_axes=("data",), model_axis="data")
     with pytest.warns(DeprecationWarning, match="detect_clusters_parallel"):
         par = detect_clusters_parallel(blobs.points, cfg,

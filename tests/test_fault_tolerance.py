@@ -88,12 +88,13 @@ def test_elastic_reshard_on_restore(tmp_path):
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import save_checkpoint, restore_checkpoint
-        mesh1 = jax.make_mesh((8,), ("data",))
+        from repro.distributed.context import make_mesh
+        mesh1 = make_mesh((8,), ("data",))
         x = jax.device_put(jnp.arange(64.0).reshape(8, 8),
                            NamedSharding(mesh1, P("data", None)))
         save_checkpoint(r"{tmp_path}", 3, {{"x": x}})
         # "restart" on a different mesh shape
-        mesh2 = jax.make_mesh((2, 4), ("a", "b"))
+        mesh2 = make_mesh((2, 4), ("a", "b"))
         sh = {{"x": NamedSharding(mesh2, P("b", "a"))}}
         step, tree = restore_checkpoint(r"{tmp_path}", 3, {{"x": x}}, sh)
         assert step == 3

@@ -112,11 +112,9 @@ def test_assign_kernel(m, n_clusters, a, d):
     dens = jnp.asarray(rng.uniform(0.4, 1.0, n_clusters), jnp.float32)
     k = jnp.float32(0.5)
     thr = jnp.float32(0.5)
-    sup_flat = sup_v.reshape(n_clusters * a, d)
-    w_mat = ref.assign_weight_matrix(sup_w)
-    gl, gs = assign_pallas(q, sup_flat, w_mat, dens, k, thr, bm=64,
+    gl, gs = assign_pallas(q, sup_v, sup_w, dens, k, thr, bm=64,
                            interpret=True)
-    wl, ws = ref.assign_ref(q, sup_flat, w_mat, dens, k, thr)
+    wl, ws = ref.assign_ref(q, sup_v, sup_w, dens, k, thr)
     np.testing.assert_array_equal(np.asarray(gl), np.asarray(wl))
     np.testing.assert_allclose(np.asarray(gs), np.asarray(ws),
                                rtol=1e-5, atol=1e-5)
@@ -173,9 +171,8 @@ def test_assign_ref_matches_legacy_predict_scores():
     ok = scores[np.arange(m), best] >= thr * dens[best]
     want = np.where(ok, best, -1).astype(np.int32)
 
-    got, _ = ref.assign_ref(q, sup_v.reshape(-1, d),
-                            ref.assign_weight_matrix(sup_w),
-                            jnp.asarray(dens), k, jnp.float32(thr))
+    got, _ = ref.assign_ref(q, sup_v, sup_w, jnp.asarray(dens), k,
+                            jnp.float32(thr))
     np.testing.assert_array_equal(np.asarray(got), want)
 
 

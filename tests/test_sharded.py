@@ -21,7 +21,7 @@ from repro.core.palid import detect_clusters_parallel
 from repro.core.roi import estimate_roi
 from repro.core.store import ShardedStore, build_store, global_bucket_sizes, take
 from repro.data import auto_lsh_params, make_blobs_with_noise
-from repro.distributed.context import MeshContext
+from repro.distributed.context import MeshContext, make_mesh
 from repro.lsh.pstable import bucket_sizes, build_lsh
 from repro.utils import canonical_labels as canonical
 
@@ -132,7 +132,7 @@ def test_serial_parallel_sharded_label_parity(blobs, lshp):
     rng = jax.random.PRNGKey(0)
     ser = detect_clusters(blobs.points, cfg, rng)
     shd = detect_clusters_sharded(blobs.points, cfg, rng, n_shards=5)
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     ctx = MeshContext(mesh=mesh, data_axes=("data",), model_axis="data")
     par = detect_clusters_parallel(blobs.points, cfg, rng, ctx)
     psh = detect_clusters_parallel(blobs.points, cfg, rng, ctx,
