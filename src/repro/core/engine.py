@@ -44,7 +44,6 @@ suite over every engine x exhaustive mode).
 from __future__ import annotations
 
 import functools
-import time
 import warnings
 from typing import Optional, Protocol
 
@@ -70,6 +69,8 @@ from repro.distributed.context import MeshContext, make_mesh
 from repro.distributed.shardings import store_specs
 from repro.lsh.pstable import (bucket_sizes, build_lsh, hash_queries,
                                shard_bucket_windows_host)
+from repro.utils import trace
+from repro.utils.trace import span
 
 __all__ = ["Engine", "EngineSpec", "Clustering", "DataSource", "fit",
            "make_engine", "resolve_claims", "ReplicatedEngine",
@@ -639,15 +640,14 @@ class StreamedEngine(_EngineBase):
                 # identical to the synchronous path)
                 for pos, s, bundle in self._pipeline.stream(routed):
                     pts_s, sk, pm, gmap = bundle
-                    t0 = time.perf_counter()
-                    carry = _stream_chunk_batch(
-                        carry, pts_s, sk, pm, gmap, keys,
-                        jnp.asarray(st[pos]), jnp.asarray(lo[pos]),
-                        jnp.asarray(hi[pos]), roi.center, roi.radius,
-                        active, sup_idx, sup_mask,
-                        jnp.asarray(touch[:, s]), probe, cfg.p, cfg.backend,
-                        cfg.dtype)
-                    self.stats.add("compute_s", time.perf_counter() - t0)
+                    with span("alid.stream.chunk", self.stats, "compute_s"):
+                        carry = _stream_chunk_batch(
+                            carry, pts_s, sk, pm, gmap, keys,
+                            jnp.asarray(st[pos]), jnp.asarray(lo[pos]),
+                            jnp.asarray(hi[pos]), roi.center, roi.radius,
+                            active, sup_idx, sup_mask,
+                            jnp.asarray(touch[:, s]), probe, cfg.p,
+                            cfg.backend, cfg.dtype)
                 del pts_s, sk, pm, gmap, bundle, st, lo, hi
             psi_idx, psi_valid, psi_v, n_cand = _finalize_batch(carry)
 
@@ -801,32 +801,40 @@ def fit(data, cfg: ALIDConfig = ALIDConfig(),
     Returns a `Clustering` carrying per-cluster weighted supports, so the
     result can `predict` new points and serialize without the dataset.
     """
-    source = resilient(as_source(data), retry_policy)
-    rng = jax.random.PRNGKey(0) if rng is None else rng
-    n = source.n
+    with span("alid.fit"):
+        source = resilient(as_source(data), retry_policy)
+        rng = jax.random.PRNGKey(0) if rng is None else rng
 
-    owns_engine = engine is None
-    if engine is None:
-        engine = make_engine(cfg.spec)
-    rng, kb = jax.random.split(rng)
-    engine.build_source(source, cfg, kb)
-    try:
-        return _fit_loop(source, cfg, rng, engine,
-                         checkpoint_dir=checkpoint_dir,
-                         checkpoint_every=max(1, int(checkpoint_every)),
-                         resume=resume, crash_at_round=int(crash_at_round))
-    finally:
-        if owns_engine:
-            engine.close()
+        owns_engine = engine is None
+        if engine is None:
+            engine = make_engine(cfg.spec)
+        rng, kb = jax.random.split(rng)
+        with span("alid.build"):
+            engine.build_source(source, cfg, kb)
+            bsizes_np = np.asarray(engine.bucket_sizes)
+        try:
+            return _fit_loop(source, cfg, rng, engine, bsizes_np,
+                             checkpoint_dir=checkpoint_dir,
+                             checkpoint_every=max(1, int(checkpoint_every)),
+                             resume=resume,
+                             crash_at_round=int(crash_at_round))
+        finally:
+            if owns_engine:
+                engine.close()
 
 
 def _fit_loop(source: DataSource, cfg: ALIDConfig, rng: jax.Array,
-              engine: Engine, checkpoint_dir: Optional[str] = None,
+              engine: Engine, bsizes_np: np.ndarray,
+              checkpoint_dir: Optional[str] = None,
               checkpoint_every: int = 1, resume: bool = False,
               crash_at_round: int = 0) -> Clustering:
+    """The peel loop of `fit`, over a built engine whose bucket sizes the
+    host holds as `bsizes_np`. Each iteration is one `alid.round` span
+    (the last may only find that no seed is left); while a profile is
+    being taken it also counts seeds, accepted clusters, resamples and the
+    ALID iterations of the seed lanes (`repro.utils.trace`)."""
     n = source.n
     bsizes = engine.bucket_sizes
-    bsizes_np = np.asarray(bsizes)
     stats = getattr(engine, "stats", None)
     cap, d = cfg.cap, source.dim
 
@@ -879,105 +887,131 @@ def _fit_loop(source: DataSource, cfg: ALIDConfig, rng: jax.Array,
     rounds = start_round
 
     for rounds in range(start_round + 1, cfg.max_rounds + 1):
-        if crash_at_round and rounds == crash_at_round:
-            raise RuntimeError(f"injected crash at round {rounds}")
-        if not bool(jnp.any(seed_valid)):
-            break
-        if not cfg.exhaustive and not any_eligible:
-            break
-        seeds_np = np.asarray(seeds)
-        valid_np = np.asarray(seed_valid)
-        peeled_seeds = seeds_np[valid_np]
+        with span("alid.round", round=rounds) as round_span:
+            if crash_at_round and rounds == crash_at_round:
+                raise RuntimeError(f"injected crash at round {rounds}")
+            with span("alid.round.wait"):
+                seeds_np = np.asarray(seeds)
+                valid_np = np.asarray(seed_valid)
+            n_valid = int(valid_np.sum())
+            round_span.annotate(seeds_valid=n_valid)
+            if not n_valid:
+                break
+            if not cfg.exhaustive and not any_eligible:
+                break
+            peeled_seeds = seeds_np[valid_np]
+            trace.count("alid.seeds_valid", n_valid)
 
-        # ---- speculative round r+1 sampling, launched BEFORE round r runs:
-        # the seeds themselves are guaranteed to peel, claims are not known
-        # yet — validated against the actual claims below
-        rng, kr_next = jax.random.split(rng)
-        spec_active = active.at[jnp.asarray(peeled_seeds)].set(False)
-        spec_seeds, spec_valid, _ = _sample_seeds(spec_active, bsizes,
-                                                  kr_next, cfg)
-        engine.prepare_round(spec_seeds)
-        if stats is not None:
-            stats.add("rounds_speculated")
+            # ---- speculative round r+1 sampling, launched BEFORE round r
+            # runs: the seeds themselves are guaranteed to peel, claims are
+            # not known yet — validated against the actual claims below
+            with span("alid.round.sample"):
+                rng, kr_next = jax.random.split(rng)
+                spec_active = active.at[jnp.asarray(peeled_seeds)].set(False)
+                spec_seeds, spec_valid, _ = _sample_seeds(spec_active, bsizes,
+                                                          kr_next, cfg)
+                engine.prepare_round(spec_seeds)
+                if stats is not None:
+                    stats.add("rounds_speculated")
 
-        claimed, best_row, results = engine.run_round(active, seeds,
-                                                      seed_valid)
+            with span("alid.round.launch"):
+                claimed, best_row, results = engine.run_round(active, seeds,
+                                                              seed_valid)
 
-        claimed_np = np.asarray(claimed)
-        row_np = np.asarray(best_row)
-        dens_np = np.asarray(results.density)
-        member_np = np.asarray(results.member_idx)
-        weight_np = np.asarray(results.member_w)
-        # peel everything claimed + the seeds themselves (guarantees
-        # progress); done FIRST so next round's seeds finalize — and the
-        # engine's background seed fetch keeps running — while the label
-        # bookkeeping below touches the source
-        new_inactive = claimed_np.copy()
-        new_inactive[peeled_seeds] = True
-        active_np &= ~new_inactive
-        active = jnp.asarray(active_np)
+            with span("alid.round.wait"):
+                claimed_np = np.asarray(claimed)
+                row_np = np.asarray(best_row)
+                dens_np = np.asarray(results.density)
+                member_np = np.asarray(results.member_idx)
+                weight_np = np.asarray(results.member_w)
+                # a lane's own ALID iterations against the batch's slowest,
+                # which every lane of the vmapped loop executes
+                n_outer = (np.asarray(results.n_outer) if trace.recording()
+                           else None)
+            if n_outer is not None:
+                trace.count("alid.lane_iters_useful",
+                            int(n_outer[valid_np].sum()))
+                trace.count("alid.lane_iters_executed",
+                            n_outer.size * int(n_outer.max()))
+            # peel everything claimed + the seeds themselves (guarantees
+            # progress); done FIRST so next round's seeds finalize — and the
+            # engine's background seed fetch keeps running — while the label
+            # bookkeeping below touches the source
+            new_inactive = claimed_np.copy()
+            new_inactive[peeled_seeds] = True
+            active_np &= ~new_inactive
+            active = jnp.asarray(active_np)
 
-        # ---- validate the speculation: exact unless a speculated winner
-        # was claimed away (scores elsewhere only dropped to -inf, which
-        # cannot change a Gumbel top-k it did not win)
-        spec_seeds_np = np.asarray(spec_seeds)
-        if claimed_np[spec_seeds_np[np.asarray(spec_valid)]].any():
-            spec_seeds, spec_valid, _ = _sample_seeds(active, bsizes,
-                                                      kr_next, cfg)
-            engine.prepare_round(spec_seeds)
-            if stats is not None:
-                stats.add("rounds_resampled")
-        seeds, seed_valid = spec_seeds, spec_valid
-        any_eligible = bool((active_np
-                             & (bsizes_np > cfg.min_bucket)).any())
+            # ---- validate the speculation: exact unless a speculated winner
+            # was claimed away (scores elsewhere only dropped to -inf, which
+            # cannot change a Gumbel top-k it did not win)
+            with span("alid.round.resample"):
+                spec_seeds_np = np.asarray(spec_seeds)
+                if claimed_np[spec_seeds_np[np.asarray(spec_valid)]].any():
+                    spec_seeds, spec_valid, _ = _sample_seeds(
+                        active, bsizes, kr_next, cfg)
+                    engine.prepare_round(spec_seeds)
+                    trace.count("alid.rounds_resampled")
+                    if stats is not None:
+                        stats.add("rounds_resampled")
+                seeds, seed_valid = spec_seeds, spec_valid
+                any_eligible = bool((active_np
+                                     & (bsizes_np > cfg.min_bucket)).any())
 
-        # Assign labels for winning rows that clear the density threshold —
-        # ONE segment pass (stable argsort groups claimed points by winning
-        # row; np.unique yields the rows in ascending order, matching the
-        # label numbering of the historical per-row Python loop, which was
-        # O(rounds·seeds) host work and would bottleneck streamed rounds).
-        claimed_pts = np.where(claimed_np)[0]
-        grp = np.argsort(row_np[claimed_pts], kind="stable")
-        sorted_pts = claimed_pts[grp]
-        uniq_rows, counts = np.unique(row_np[claimed_pts],
-                                      return_counts=True)
-        keep = (dens_np[uniq_rows] >= cfg.density_min) & (counts > 1)
-        lab = np.full(uniq_rows.shape[0], -1, np.int32)
-        lab[keep] = next_label + np.arange(int(keep.sum()), dtype=np.int32)
-        labels[sorted_pts] = np.repeat(lab, counts)
-        for row in uniq_rows[keep]:
-            densities.append(float(dens_np[row]))
-            midx, mw = member_np[row], weight_np[row]
-            valid = (midx >= 0) & (mw > 0)
-            w = np.where(valid, mw, 0.0).astype(np.float32)
-            w /= max(float(w.sum()), 1e-12)
-            sup_idx.append(np.where(valid, midx, -1).astype(np.int32))
-            sup_w.append(w)
-            sup_v.append(np.asarray(
-                source.sample(np.clip(midx, 0, n - 1)), np.float32)
-                * valid[:, None])
-        next_label += int(keep.sum())
-        if not active_np.any():
-            break
-        # round-level resume point — saved only when the loop continues, so
-        # a resumed run re-enters at round+1 exactly where the uninterrupted
-        # run did (crashing AFTER the final round just re-runs it, which is
-        # deterministic and lands on the same labels)
-        if checkpoint_dir is not None and rounds % checkpoint_every == 0:
-            _save_fit_checkpoint(checkpoint_dir, rounds, labels, active_np,
-                                 rng, seeds, seed_valid, any_eligible,
-                                 densities, sup_idx, sup_w, sup_v,
-                                 next_label, cap, d)
+            # Assign labels for winning rows that clear the density
+            # threshold — ONE segment pass (stable argsort groups claimed
+            # points by winning row; np.unique yields the rows in ascending
+            # order, matching the label numbering of the historical per-row
+            # Python loop, which was O(rounds·seeds) host work and would
+            # bottleneck streamed rounds).
+            with span("alid.round.label"):
+                claimed_pts = np.where(claimed_np)[0]
+                grp = np.argsort(row_np[claimed_pts], kind="stable")
+                sorted_pts = claimed_pts[grp]
+                uniq_rows, counts = np.unique(row_np[claimed_pts],
+                                              return_counts=True)
+                keep = (dens_np[uniq_rows] >= cfg.density_min) & (counts > 1)
+                n_keep = int(keep.sum())
+                lab = np.full(uniq_rows.shape[0], -1, np.int32)
+                lab[keep] = next_label + np.arange(n_keep, dtype=np.int32)
+                labels[sorted_pts] = np.repeat(lab, counts)
+                for row in uniq_rows[keep]:
+                    densities.append(float(dens_np[row]))
+                    midx, mw = member_np[row], weight_np[row]
+                    valid = (midx >= 0) & (mw > 0)
+                    w = np.where(valid, mw, 0.0).astype(np.float32)
+                    w /= max(float(w.sum()), 1e-12)
+                    sup_idx.append(np.where(valid, midx, -1).astype(np.int32))
+                    sup_w.append(w)
+                    sup_v.append(np.asarray(
+                        source.sample(np.clip(midx, 0, n - 1)), np.float32)
+                        * valid[:, None])
+                next_label += n_keep
+            trace.count("alid.clusters_accepted", n_keep)
+            if not active_np.any():
+                break
+            # round-level resume point — saved only when the loop continues,
+            # so a resumed run re-enters at round+1 exactly where the
+            # uninterrupted run did (crashing AFTER the final round just
+            # re-runs it, which is deterministic and lands on the same
+            # labels)
+            if checkpoint_dir is not None and rounds % checkpoint_every == 0:
+                with span("alid.round.checkpoint"):
+                    _save_fit_checkpoint(checkpoint_dir, rounds, labels,
+                                         active_np, rng, seeds, seed_valid,
+                                         any_eligible, densities, sup_idx,
+                                         sup_w, sup_v, next_label, cap, d)
 
-    return Clustering(
-        labels=labels,
-        densities=np.asarray(densities, np.float32),
-        n_rounds=rounds,
-        k=float(engine.k),
-        support_idx=(np.stack(sup_idx) if sup_idx
-                     else np.zeros((0, cap), np.int32)),
-        support_w=(np.stack(sup_w) if sup_w
-                   else np.zeros((0, cap), np.float32)),
-        support_v=(np.stack(sup_v).astype(np.float32) if sup_v
-                   else np.zeros((0, cap, d), np.float32)),
-    )
+    with span("alid.finish"):
+        return Clustering(
+            labels=labels,
+            densities=np.asarray(densities, np.float32),
+            n_rounds=rounds,
+            k=float(engine.k),
+            support_idx=(np.stack(sup_idx) if sup_idx
+                         else np.zeros((0, cap), np.int32)),
+            support_w=(np.stack(sup_w) if sup_w
+                       else np.zeros((0, cap), np.float32)),
+            support_v=(np.stack(sup_v).astype(np.float32) if sup_v
+                       else np.zeros((0, cap, d), np.float32)),
+        )

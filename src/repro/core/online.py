@@ -45,7 +45,6 @@ from __future__ import annotations
 
 import functools
 import tempfile
-import threading
 from typing import NamedTuple, Optional, Sequence
 
 import jax
@@ -61,6 +60,7 @@ from repro.core.civs import _ROUTE_EPS
 from repro.core.lid import LIDState, density, lid_solve, refresh_ax
 from repro.core.roi import estimate_roi
 from repro.core.source import as_source, is_data_source
+from repro.utils.trace import Counters
 
 __all__ = ["OnlineClustering", "Epoch", "EpochVerifyError", "OnlineStats"]
 
@@ -83,36 +83,13 @@ class EpochVerifyError(RuntimeError):
         self.problems = problems
 
 
-class OnlineStats:
-    """Counters for the online-update path (PipelineStats style)."""
+class OnlineStats(Counters):
+    """Counters for the online-update path."""
 
     _FIELDS = ("inserted", "deleted", "routed", "buffered", "flushes",
                "reconverges", "noop_reconverges", "absorbed", "dropped",
                "dissolved", "new_clusters", "overflowed", "commits",
                "rollbacks")
-
-    def __init__(self) -> None:
-        for f in self._FIELDS:
-            setattr(self, f, 0)
-        self._lock = threading.Lock()
-
-    def add(self, field: str, amount: int = 1) -> None:
-        with self._lock:
-            setattr(self, field, getattr(self, field) + amount)
-
-    def snapshot(self) -> dict:
-        return {f: int(getattr(self, f)) for f in self._FIELDS}
-
-    def report(self) -> str:
-        s = self.snapshot()
-        return ("online: "
-                f"inserted={s['inserted']} deleted={s['deleted']} "
-                f"routed={s['routed']} buffered={s['buffered']} "
-                f"flushes={s['flushes']} (+{s['new_clusters']} clusters) | "
-                f"reconverges={s['reconverges']} "
-                f"(noop={s['noop_reconverges']}) absorbed={s['absorbed']} "
-                f"dropped={s['dropped']} dissolved={s['dissolved']} | "
-                f"commits={s['commits']} rollbacks={s['rollbacks']}")
 
 
 # ------------------------------------------------------------- jit helpers --

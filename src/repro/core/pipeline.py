@@ -46,7 +46,6 @@ import os
 import queue
 import tempfile
 import threading
-import time
 import warnings
 import zlib
 from collections import OrderedDict
@@ -57,6 +56,7 @@ import numpy as np
 
 from repro.core.resilience import (CorruptionError, DEFAULT_RETRY,
                                    RetryPolicy)
+from repro.utils.trace import Counters, span
 
 __all__ = ["PipelineStats", "ScratchShards", "ShardBundleCache",
            "ShardPipeline", "DEFAULT_CACHE_BYTES"]
@@ -68,7 +68,7 @@ def _crc32(arr: np.ndarray) -> int:
 DEFAULT_CACHE_BYTES = 256 * 2**20          # 256 MiB of hot shard payloads
 
 
-class PipelineStats:
+class PipelineStats(Counters):
     """Per-engine counters for the read / put / compute stage breakdown.
 
     Stage seconds are HOST-SIDE times, accumulated where the work is issued:
@@ -90,19 +90,6 @@ class PipelineStats:
                "rounds_speculated", "rounds_resampled", "read_retries",
                "corruptions", "tier_fallbacks", "reader_deaths",
                "readers_abandoned")
-
-    def __init__(self) -> None:
-        for f in self._FIELDS:
-            setattr(self, f, 0.0 if f.endswith("_s") else 0)
-        self._lock = threading.Lock()
-
-    def add(self, field: str, amount=1) -> None:
-        with self._lock:
-            setattr(self, field, getattr(self, field) + amount)
-
-    def snapshot(self) -> dict:
-        return {f: (float(v) if isinstance(v := getattr(self, f), float)
-                    else int(v)) for f in self._FIELDS}
 
     def report(self) -> str:
         s = self.snapshot()
@@ -387,9 +374,8 @@ class ShardPipeline:
             if self.cache.corrupt_evictions > corrupt0:
                 stats.add("corruptions")
                 stats.add("tier_fallbacks")
-        t0 = time.perf_counter()
-        pts = self._read_points(s, gen)
-        stats.add("read_s", time.perf_counter() - t0)
+        with span("pipeline.read", stats, "read_s"):
+            pts = self._read_points(s, gen)
         bundle = (pts, self.store.sorted_keys[s], self.store.perm[s],
                   self.store.global_idx[s])
         if self.cache is not None:
@@ -397,10 +383,8 @@ class ShardPipeline:
         return bundle
 
     def _device_put(self, bundle: tuple):
-        t0 = time.perf_counter()
-        dev = jax.device_put(bundle)
-        self.stats.add("put_s", time.perf_counter() - t0)
-        return dev
+        with span("pipeline.put", self.stats, "put_s"):
+            return jax.device_put(bundle)
 
     # -- streaming ---------------------------------------------------------
     def stream(self, routed: Iterable[int]) -> Iterator[tuple]:
@@ -455,9 +439,8 @@ class ShardPipeline:
         reader.start()
         try:
             for pos, s in enumerate(routed):
-                t0 = time.perf_counter()
-                item = ring.get()
-                self.stats.add("wait_s", time.perf_counter() - t0)
+                with span("pipeline.wait", self.stats, "wait_s"):
+                    item = ring.get()
                 if isinstance(item, _ProducerError):
                     # the reader died before producing bundle `pos` (its
                     # error lands in FIFO order after its last good bundle).

@@ -26,8 +26,9 @@ scale layer on top of the same fused assignment kernel
                       `policy="reject"` raises `QueueFull` at submit,
                       `policy="block"` makes submit wait for space
                       (backpressure), with an optional timeout.
-  * `ServingStats`  — PipelineStats-style counters: queue depth, batch
-                      occupancy, and per-stage wait / pack / compute timers.
+  * `ServingStats`  — counters on the shared `repro.utils.trace.Counters`
+                      base: queue depth, batch occupancy, and per-stage
+                      wait / pack / compute timers fed by the worker's spans.
 
 Why continuous batching matters here: ALID's localization makes assignment
 O(C·cap) per query independent of n (paper Sec. 4), so the serving cost is
@@ -41,6 +42,7 @@ is what `benchmarks/serving_latency.py` measures (BENCH_serving.json).
 from __future__ import annotations
 
 import functools
+import itertools
 import threading
 import time
 from collections import deque
@@ -53,6 +55,7 @@ import numpy as np
 
 from repro.core.alid import Clustering, assign_labels_source
 from repro.kernels import ops
+from repro.utils.trace import Counters, span
 
 
 class QueueFull(RuntimeError):
@@ -106,13 +109,15 @@ def _try_set_running(fut: Future) -> bool:
 
 
 # ---------------------------------------------------------------- metrics --
-class ServingStats:
-    """Serving counters in the `core.pipeline.PipelineStats` style.
+class ServingStats(Counters):
+    """Serving counters.
 
-    Stage seconds are host-side: `wait_s` is worker idle time between
-    batches (queue empty), `pack_s` the host packing of queued requests into
-    the staging buffer, `compute_s` the device upload + fused assign + sync
-    per batch, and `queue_wait_s` the SUM over requests of (pack start −
+    Stage seconds are host-side, each fed by the worker span of the same
+    stage: `wait_s` is worker idle time between batches (`serve.idle`,
+    queue empty), `pack_s` the host packing of queued requests into the
+    staging buffer (`serve.pack`), `compute_s` the device upload + fused
+    assign + sync per batch (`serve.upload` + `serve.launch` +
+    `serve.wait`), and `queue_wait_s` the SUM over requests of (pack start −
     submit) — queue_wait_s / served is the mean queueing delay. Occupancy =
     slots_filled / (batches · batch_slots): low occupancy under load means
     the device is spinning on mostly-empty batches, high occupancy with
@@ -124,23 +129,6 @@ class ServingStats:
                "version_swaps", "rollbacks", "worker_deaths", "respawns",
                "failed_shutdowns", "queue_wait_s", "pack_s", "compute_s",
                "wait_s")
-
-    def __init__(self) -> None:
-        for f in self._FIELDS:
-            setattr(self, f, 0.0 if f.endswith("_s") else 0)
-        self._lock = threading.Lock()
-
-    def add(self, field: str, amount=1) -> None:
-        with self._lock:
-            setattr(self, field, getattr(self, field) + amount)
-
-    def peak(self, field: str, value) -> None:
-        with self._lock:
-            setattr(self, field, max(getattr(self, field), value))
-
-    def snapshot(self) -> dict:
-        return {f: (float(v) if isinstance(v := getattr(self, f), float)
-                    else int(v)) for f in self._FIELDS}
 
     def occupancy(self, batch_slots: int) -> float:
         s = self.snapshot()
@@ -202,7 +190,8 @@ class Tenant:
 
     def __init__(self, name: str, clustering: Clustering, *,
                  threshold: float = 0.5, backend: str = "auto",
-                 version: int = 0, epoch: int = -1):
+                 version: int = 0, epoch: int = -1,
+                 stats: Optional[ServingStats] = None):
         assert clustering.support_v is not None, (
             "Tenant needs a Clustering with stored supports "
             "(produced by repro.core.engine.fit)")
@@ -220,6 +209,7 @@ class Tenant:
         self._dens = jnp.asarray(clustering.densities, jnp.float32)
         self._k = jnp.float32(clustering.k)
         self._thr = jnp.float32(threshold)
+        self._stats = stats        # the serving counters batches feed
         # double-buffered pinned staging pairs, sized lazily per batch_slots
         self._staging: dict[int, list[tuple[np.ndarray, np.ndarray]]] = {}
         self._flip = 0
@@ -250,13 +240,19 @@ class Tenant:
     def assign_np(self, q: np.ndarray, valid: np.ndarray) -> np.ndarray:
         """Assign one packed batch: (slots, d) f32 + (slots,) bool validity
         -> (slots,) int32 labels, -1 on pad slots and below-threshold real
-        slots. Synchronous (blocks until device results are on host)."""
+        slots. Synchronous (blocks until device results are on host). Its
+        upload, launch and wait spans add to the server's `compute_s`."""
         if self.n_clusters == 0:
             return np.full((q.shape[0],), -1, np.int32)
-        labels = _assign_jit()(jnp.asarray(q), jnp.asarray(valid),
-                               self._sup_v, self._sup_w, self._dens,
-                               self._k, self._thr, backend=self.backend)
-        return np.asarray(labels)
+        stats = self._stats
+        with span("serve.upload", stats, "compute_s"):
+            q_dev, valid_dev = jnp.asarray(q), jnp.asarray(valid)
+        with span("serve.launch", stats, "compute_s"):
+            labels = _assign_jit()(q_dev, valid_dev, self._sup_v,
+                                   self._sup_w, self._dens, self._k,
+                                   self._thr, backend=self.backend)
+        with span("serve.wait", stats, "compute_s"):
+            return np.asarray(labels)
 
     def assign_source(self, source, batch_size: int = 256) -> np.ndarray:
         """Bulk offline counterpart: label every row of a DataSource against
@@ -274,14 +270,17 @@ class Tenant:
 
 # ----------------------------------------------------------------- server --
 class _Request:
-    __slots__ = ("tenant_key", "vec", "future", "t_submit", "deadline")
+    __slots__ = ("tenant_key", "vec", "future", "t_submit", "deadline",
+                 "seq")
 
-    def __init__(self, tenant_key, vec, future, t_submit, deadline=None):
+    def __init__(self, tenant_key, vec, future, t_submit, deadline=None,
+                 seq=0):
         self.tenant_key = tenant_key
         self.vec = vec
         self.future = future
         self.t_submit = t_submit
         self.deadline = deadline   # absolute time.monotonic(), or None
+        self.seq = seq             # submit order, named by the batch span
 
 
 class ClusterServer:
@@ -351,6 +350,8 @@ class ClusterServer:
         self._respawns = 0
         self._kill_worker = False  # fault-injection flag (tests/chaos demo)
         self._inflight: list[_Request] = []  # batch the worker currently owns
+        self._submit_seq = 0       # next request's sequence number
+        self._batch_seq = itertools.count(1)   # batch sequence numbers
         self._worker: Optional[threading.Thread] = None
         if start:
             self.start()
@@ -362,7 +363,7 @@ class ClusterServer:
         """Register (or replace) a resident store under (name, version).
         Supports are uploaded to device here, once."""
         t = Tenant(name, clustering, threshold=threshold, backend=backend,
-                   version=version, epoch=epoch)
+                   version=version, epoch=epoch, stats=self.stats)
         with self._lock:
             if self._stopping:
                 raise RuntimeError("server is closed")
@@ -399,7 +400,7 @@ class ClusterServer:
             versions = [v for (n, v) in self._tenants if n == name]
             version = max(versions) + 1 if versions else 0
         t = Tenant(name, clustering, threshold=threshold, backend=backend,
-                   version=version, epoch=epoch)
+                   version=version, epoch=epoch, stats=self.stats)
         with self._lock:
             if self._stopping:
                 raise RuntimeError("server is closed")
@@ -512,7 +513,9 @@ class ClusterServer:
                             f"after {timeout}s (policy=block)")
             fut: Future = Future()
             self._queues[key].append(
-                _Request(key, vec, fut, time.perf_counter(), dl))
+                _Request(key, vec, fut, time.perf_counter(), dl,
+                         self._submit_seq))
+            self._submit_seq += 1
             self._pending += 1
             self.stats.add("submitted")
             self.stats.peak("queue_depth_peak", self._pending)
@@ -621,8 +624,7 @@ class ClusterServer:
 
     def _serve_loop(self) -> None:
         while True:
-            t_idle = time.perf_counter()
-            with self._work:
+            with span("serve.idle", self.stats, "wait_s"), self._work:
                 while (self._pending == 0 and not self._stopping
                        and not self._kill_worker):
                     self._work.wait(0.1)
@@ -632,7 +634,6 @@ class ClusterServer:
                 if self._pending == 0 and self._stopping:
                     return
                 popped = self._next_batch()
-            self.stats.add("wait_s", time.perf_counter() - t_idle)
             if popped:
                 self._serve_batch(*popped)
 
@@ -640,46 +641,52 @@ class ClusterServer:
         """Serve one popped batch against its snapshotted Tenant. The
         snapshot (not the live registry) is what gets served: every label in
         the batch comes from ONE (name, version) clustering even if a swap
-        or removal lands mid-compute."""
-        t_pack = time.perf_counter()
-        now = time.monotonic()
-        live: list[tuple[int, _Request]] = []
-        expired: list[_Request] = []
-        for r in batch:
-            if r.deadline is not None and now > r.deadline:
-                expired.append(r)
-            # a future cancelled while queued never reaches the device
-            elif _try_set_running(r.future):
-                live.append((len(live), r))
-            else:
-                self.stats.add("cancelled")
-        for r in expired:   # resolve outside any lock, before the compute
-            self.stats.add("expired")
-            _safe_set_exception(r.future, DeadlineExceeded(
-                "request deadline expired before it was packed"))
-        q, valid = tenant.staging(self.batch_slots)
-        q[:] = 0.0
-        valid[:] = False
-        for i, r in live:
-            q[i] = r.vec
-            valid[i] = True
-            self.stats.add("queue_wait_s", t_pack - r.t_submit)
-        t_comp = time.perf_counter()
-        self.stats.add("pack_s", t_comp - t_pack)
-        try:
-            labels = tenant.assign_np(q, valid)
-        except Exception as e:               # resolve, don't kill the worker
-            for _, r in live:
-                _safe_set_exception(r.future, e)
-            with self._lock:
-                self._inflight = []
-            return
-        self.stats.add("compute_s", time.perf_counter() - t_comp)
-        self.stats.add("batches")
-        self.stats.add("slots_filled", len(live))
-        self.stats.add("served", len(live))
-        for i, r in live:
-            _safe_set_result(r.future, int(labels[i]))
+        or removal lands mid-compute. Its `serve.batch` span names the
+        batch, its tenant and the sequence numbers of its requests."""
+        with span("serve.batch", batch=next(self._batch_seq),
+                  tenant=tenant.name, version=tenant.version,
+                  first=batch[0].seq, last=batch[-1].seq) as batch_span:
+            with span("serve.pack", self.stats, "pack_s"):
+                t_pack = time.perf_counter()
+                now = time.monotonic()
+                live: list[tuple[int, _Request]] = []
+                expired: list[_Request] = []
+                for r in batch:
+                    if r.deadline is not None and now > r.deadline:
+                        expired.append(r)
+                    # a future cancelled while queued never reaches the device
+                    elif _try_set_running(r.future):
+                        live.append((len(live), r))
+                    else:
+                        self.stats.add("cancelled")
+                for r in expired:   # resolved outside any lock, first
+                    self.stats.add("expired")
+                    _safe_set_exception(r.future, DeadlineExceeded(
+                        "request deadline expired before it was packed"))
+                q, valid = tenant.staging(self.batch_slots)
+                q[:] = 0.0
+                valid[:] = False
+                queued_s = 0.0
+                for i, r in live:
+                    q[i] = r.vec
+                    valid[i] = True
+                    queued_s += t_pack - r.t_submit
+                self.stats.add("queue_wait_s", queued_s)
+            batch_span.annotate(slots=len(live))
+            try:
+                labels = tenant.assign_np(q, valid)
+            except Exception as e:           # resolve, don't kill the worker
+                for _, r in live:
+                    _safe_set_exception(r.future, e)
+                with self._lock:
+                    self._inflight = []
+                return
+            self.stats.add("batches")
+            self.stats.add("slots_filled", len(live))
+            self.stats.add("served", len(live))
+            with span("serve.resolve"):
+                for i, r in live:
+                    _safe_set_result(r.future, int(labels[i]))
         # only after every future is resolved does the worker disown the
         # batch — an exception anywhere above leaves _inflight set so the
         # supervisor can fail the remainder
