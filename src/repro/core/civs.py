@@ -15,7 +15,9 @@ the whole step vmaps over a batch of seeds.
 Two retrieval engines sit behind the one `civs_update` signature:
 
   * replicated — `points`/`tables` are the full dataset + monolithic LSH
-    (original path);
+    (original path). Every query is a support row, i.e. a data row, so its
+    buckets come from the tables' per-point directory (`pstable.probe_rows`)
+    instead of hashing and searching;
   * sharded / out-of-core — `points` is a `repro.core.store.ShardedStore`
     (`tables=None`): a fori_loop walks the shards whose bounding ball can
     intersect the ROI ball, probes the shard-local tables, and folds each
@@ -47,7 +49,7 @@ from repro.kernels import ops
 from repro.core.roi import ROI
 from repro.core.store import ShardedStore
 from repro.lsh.pstable import (LSHParams, LSHTables, hash_queries,
-                               probe_tables_window, query_batch,
+                               probe_rows, probe_tables_window, query_salts,
                                shard_bucket_windows)
 
 
@@ -109,9 +111,14 @@ def rebuild_support(state: LIDState, sup_idx, sup_v, sup_x, sup_slot_mask,
 def _retrieve_replicated(roi: ROI, points, active, tables, lsh_params,
                          sup_idx, sup_v, sup_slot_mask, delta: int, p: float,
                          backend: str = "auto"):
-    """Steps 2-4 against the full dataset + monolithic LSH tables."""
+    """Steps 2-4 against the full dataset + monolithic LSH tables.
+
+    The support rows are data rows (`sup_v` = `points[sup_idx]`), so their
+    buckets are read from the directory by `sup_idx`; only the salts are
+    computed from `sup_v`, exactly as `query_batch` computes them."""
     n = points.shape[0]
-    cands = query_batch(tables, sup_v, lsh_params, backend=backend)
+    salts = query_salts(sup_v, tables.proj, tables.bias)
+    cands = probe_rows(tables, sup_idx, salts, lsh_params.probe)
     #                                                     (a_cap, L*probe)
     cands = jnp.where(sup_slot_mask[:, None], cands, -1)
     flat = cands.reshape(-1)                              # (a_cap * L * probe,)

@@ -176,6 +176,9 @@ class Engine(Protocol):
     @property
     def bucket_sizes(self) -> jax.Array: ...
 
+    @property
+    def probe_counter(self) -> str: ...
+
 
 # rows drawn for k estimation when cfg.k is None (mirrors estimate_k default)
 _K_SAMPLE = 512
@@ -184,6 +187,7 @@ _K_SAMPLE = 512
 class _EngineBase:
     def __init__(self) -> None:
         self._bsizes = None
+        self._tables = None
         self.k = None
         self._cfg: Optional[ALIDConfig] = None
         self._n = 0
@@ -224,6 +228,14 @@ class _EngineBase:
     def bucket_sizes(self) -> jax.Array:
         assert self._bsizes is not None, "call build() first"
         return self._bsizes
+
+    @property
+    def probe_counter(self) -> str:
+        """The trace counter of this engine's CIVS bucket lookups: monolithic
+        tables are read through their bucket directory, shard tables are
+        binary-searched."""
+        return ("lsh.probes_directory" if self._tables is not None
+                else "lsh.probes_searched")
 
     def prepare_round(self, seeds) -> None:
         """Optional round-level overlap hook: the driver announces the seed
@@ -929,10 +941,13 @@ def _fit_loop(source: DataSource, cfg: ALIDConfig, rng: jax.Array,
                 n_outer = (np.asarray(results.n_outer) if trace.recording()
                            else None)
             if n_outer is not None:
+                executed = n_outer.size * int(n_outer.max())
                 trace.count("alid.lane_iters_useful",
                             int(n_outer[valid_np].sum()))
-                trace.count("alid.lane_iters_executed",
-                            n_outer.size * int(n_outer.max()))
+                trace.count("alid.lane_iters_executed", executed)
+                # every CIVS pass looks up each support slot in each table
+                trace.count(engine.probe_counter,
+                            executed * cfg.a_cap * cfg.lsh.n_tables)
             # peel everything claimed + the seeds themselves (guarantees
             # progress); done FIRST so next round's seeds finalize — and the
             # engine's background seed fetch keeps running — while the label

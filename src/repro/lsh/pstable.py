@@ -3,9 +3,12 @@
 The paper's CIVS step indexes all data items with LSH. A CPU implementation
 chains hash buckets in a hash map; that is hostile to TPUs, so we realize each
 table as ONE sorted permutation of the dataset keyed by a 32-bit mixed bucket
-key. Query = binary search (searchsorted) + a bounded contiguous gather, which
-is fixed-shape and fully vectorizable / vmappable — the TPU-native analogue of
-walking a bucket's chain.
+key. A query by an arbitrary vector = binary search (searchsorted) + a bounded
+contiguous gather, which is fixed-shape and fully vectorizable / vmappable —
+the TPU-native analogue of walking a bucket's chain. A query by a DATA row
+(every CIVS query is one) skips the search: the build keeps each point's
+bucket head and size per table (`LSHTables.directory`), so the probe is one
+directory row read plus one contiguous slice of `perm` per table.
 
 h_{l,j}(v) = floor((w_{l,j} . v + b_{l,j}) / r)   w ~ N(0,1)  (p=2 stable)
 key_l(v)  = mix32(h_{l,1..m})                     (multiply-xor fold)
@@ -30,10 +33,19 @@ class LSHParams(NamedTuple):
 
 
 class LSHTables(NamedTuple):
+    """Monolithic tables over n points.
+
+    `directory` is the per-point bucket directory: row i holds point i's
+    bucket head (its bucket's first position in `sorted_keys[l]`) for each
+    table l, then its bucket size for each table, so a data row's probe
+    reads one (2L,) row instead of searching. It costs 8·L bytes a point
+    (3.2 MB at n = 100,000, L = 4: ~6% of the points at d = 128 in f32).
+    """
     proj: jax.Array         # (L, m, d)
     bias: jax.Array         # (L, m)
     sorted_keys: jax.Array  # (L, n) uint32, ascending per table
     perm: jax.Array         # (L, n) int32: position in sorted order -> data index
+    directory: jax.Array    # (n, 2L) int32: [head_0..head_{L-1}, size_0..size_{L-1}]
 
 
 class ShardedLSHTables(NamedTuple):
@@ -112,7 +124,38 @@ def build_lsh(v: jax.Array, params: LSHParams, rng: jax.Array,
     keys = hash_points(v, proj, bias, params.seg_len, backend)  # (L, n)
     order = jnp.argsort(keys, axis=1).astype(jnp.int32)          # (L, n)
     sorted_keys = jnp.take_along_axis(keys, order.astype(jnp.int32), axis=1)
-    return LSHTables(proj=proj, bias=bias, sorted_keys=sorted_keys, perm=order)
+    return LSHTables(proj=proj, bias=bias, sorted_keys=sorted_keys, perm=order,
+                     directory=_bucket_directory(sorted_keys, order))
+
+
+def _bucket_directory(sorted_keys: jax.Array, perm: jax.Array) -> jax.Array:
+    """(n, 2L) per-point bucket heads and sizes from the sorted tables.
+
+    A sorted slot starts a bucket where its key differs from the previous
+    slot's; a running max of those start positions gives every slot its
+    bucket's head. The same running max over the reversed tables gives the
+    reversed position of the bucket's last slot, hence its end. Each column
+    is then filed under the points' data indices by a scatter through
+    `perm`. (One (2L, n) running max and 1-D scatters because the TPU
+    compiler spends over a minute on a reverse running min at n = 100,000
+    and ~25 s on one (n, 2L) scatter at n = 1,000,000; these forms
+    compile in seconds.)
+    """
+    n_tables, n = sorted_keys.shape
+    pos = jnp.arange(n, dtype=jnp.int32)
+    change = sorted_keys[:, 1:] != sorted_keys[:, :-1]            # (L, n-1)
+    edge = jnp.ones((n_tables, 1), bool)
+    starts = jnp.concatenate([edge, change], axis=1)              # (L, n)
+    lasts = jnp.flip(jnp.concatenate([change, edge], axis=1), axis=1)
+    run = jax.lax.cummax(jnp.where(jnp.concatenate([starts, lasts]), pos, 0),
+                         axis=1)                                  # (2L, n)
+    head = run[:n_tables]
+    end = n - jnp.flip(run[n_tables:], axis=1)
+    cols = jnp.concatenate([head, end - head])                    # (2L, n)
+    return jnp.stack([
+        jnp.zeros((n,), jnp.int32).at[perm[c % n_tables]].set(
+            cols[c], unique_indices=True)
+        for c in range(2 * n_tables)], axis=1)
 
 
 def _query_one_table(sorted_keys: jax.Array, perm: jax.Array, key: jax.Array,
@@ -127,14 +170,20 @@ def _query_one_table(sorted_keys: jax.Array, perm: jax.Array, key: jax.Array,
     """
     start = jnp.searchsorted(sorted_keys, key, side="left")
     end = jnp.searchsorted(sorted_keys, key, side="right")
-    size = end - start
-    span = jnp.maximum(size - probe, 0)
-    offset = jnp.where(span > 0, (salt % (span.astype(jnp.uint32) + 1)).astype(start.dtype), 0)
+    offset = _window_offset(end - start, salt, probe)
     offs = jnp.arange(probe)
     pos = jnp.minimum(start + offset + offs, sorted_keys.shape[0] - 1)
-    hit = (sorted_keys[pos] == key) & (start + offset + offs < end)
-    idx = jnp.where(hit, perm[pos], -1)
-    return idx
+    # every slot of [start, end) holds `key`, so the bound is the whole test
+    return jnp.where(start + offset + offs < end, perm[pos], -1)
+
+
+def _window_offset(size: jax.Array, salt: jax.Array, probe: int) -> jax.Array:
+    """Where a bucket of `size` members starts its `probe`-wide window:
+    salt % (size - probe + 1) when the bucket is larger, else its head."""
+    span = jnp.maximum(size - probe, 0)
+    return jnp.where(span > 0,
+                     (salt % (span.astype(jnp.uint32) + 1)).astype(size.dtype),
+                     0)
 
 
 def hash_queries(q: jax.Array, proj: jax.Array, bias: jax.Array,
@@ -155,14 +204,20 @@ def hash_queries(q: jax.Array, proj: jax.Array, bias: jax.Array,
     the fused kernel exists to avoid.
     """
     keys = hash_points(q, proj, bias, seg_len, backend)              # (L, Q)
-    # analysis: allow(private-matmul): duplicate salt projection documented above — fusing it into the hash kernel would force a (Q, L, m) HBM round-trip
+    return keys, query_salts(q, proj, bias)
+
+
+def query_salts(q: jax.Array, proj: jax.Array, bias: jax.Array) -> jax.Array:
+    """Per-query probe-window salts for q:(Q,d) -> (L, Q) uint32: the
+    multiply-xor fold of the raw f32 bits of the query's projections
+    (`hash_queries` documents why)."""
+    # analysis: allow(private-matmul): duplicate salt projection documented in hash_queries — fusing it into the hash kernel would force a (Q, L, m) HBM round-trip
     z = (jnp.einsum("nd,lmd->lnm", q.astype(jnp.float32),
                     proj.astype(jnp.float32),
                     precision=jax.lax.Precision.HIGHEST)
          + bias[:, None, :].astype(jnp.float32))
     bits = jax.lax.bitcast_convert_type(z, jnp.uint32)
-    salts = _mix_fold(jax.lax.bitcast_convert_type(bits, jnp.int32))
-    return keys, salts
+    return _mix_fold(jax.lax.bitcast_convert_type(bits, jnp.int32))
 
 
 def shard_bucket_windows(sorted_keys: jax.Array, keys: jax.Array,
@@ -300,6 +355,42 @@ def query_batch(tables: LSHTables, q: jax.Array, params: LSHParams,
     return probe_tables(tables.sorted_keys, tables.perm, keys, salts, params.probe)
 
 
+def probe_rows(tables: LSHTables, idx: jax.Array, salts: jax.Array,
+               probe: int) -> jax.Array:
+    """Candidates for DATA rows idx:(Q,) -> (Q, L*probe) int32, -1 = miss.
+
+    The same candidates `query_batch` returns for the rows `points[idx]`
+    under `salts` (L, Q) (`query_salts` of those rows): a data row's key is
+    the one the build sorted by, so its bucket's head and size come from
+    its directory row, and each table's window is `probe` contiguous slots
+    of `perm` — no hashing, no binary search, no key re-check.
+
+    The TPU expands a gather of `probe` slots at an arbitrary offset into
+    a serial loop over the queries, but gathers aligned rows natively. So
+    `perm` is cut into rows of `probe` slots (padded with -1, plus one
+    spare row), the two rows that hold a window are gathered, and the
+    window is shifted into place by the bits of its offset in the row.
+    """
+    n_tables, n = tables.perm.shape
+    dirs = tables.directory[jnp.clip(idx, 0, n - 1)]              # (Q, 2L)
+    head, size = dirs[:, :n_tables].T, dirs[:, n_tables:].T       # (L, Q)
+    offset = _window_offset(size, salts, probe)
+    start = head + offset                                         # < n
+    n_rows = -(-n // probe) + 1
+    rows = jnp.pad(tables.perm, ((0, 0), (0, n_rows * probe - n)),
+                   constant_values=-1).reshape(n_tables * n_rows, probe)
+    r = (jnp.arange(n_tables, dtype=jnp.int32)[:, None] * n_rows
+         + start // probe)
+    win = jnp.concatenate([rows[r], rows[r + 1]], axis=-1)        # (L, Q, 2p)
+    shift = start % probe
+    for b in range(max(1, (probe - 1).bit_length())):
+        win = jnp.where(((shift >> b) & 1)[..., None] == 1,
+                        jnp.roll(win, -(1 << b), axis=-1), win)
+    hit = offset[..., None] + jnp.arange(probe) < size[..., None]
+    cands = jnp.where(hit, win[..., :probe], -1)                  # (L, Q, p)
+    return jnp.transpose(cands, (1, 0, 2)).reshape(idx.shape[0], -1)
+
+
 @functools.partial(jax.jit, static_argnames=("params", "backend"))
 def build_lsh_sharded(shard_points: jax.Array, valid: jax.Array,
                       params: LSHParams, rng: jax.Array,
@@ -331,11 +422,6 @@ def build_lsh_sharded(shard_points: jax.Array, valid: jax.Array,
 @jax.jit
 def bucket_sizes(tables: LSHTables) -> jax.Array:
     """Per data item: size of its bucket in table 0 (used for PALID seeding —
-    the paper samples initial vertexes from buckets with > 5 items)."""
-    sk = tables.sorted_keys[0]
-    n = sk.shape[0]
-    left = jnp.searchsorted(sk, sk, side="left")
-    right = jnp.searchsorted(sk, sk, side="right")
-    size_sorted = (right - left).astype(jnp.int32)
-    sizes = jnp.zeros((n,), jnp.int32).at[tables.perm[0]].set(size_sorted)
-    return sizes
+    the paper samples initial vertexes from buckets with > 5 items), read
+    from the build's bucket directory."""
+    return tables.directory[:, tables.perm.shape[0]]

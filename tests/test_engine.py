@@ -119,6 +119,42 @@ def test_backend_interpret_parity(small_blobs, engine, kw):
     assert res["ref"].n_rounds == res["interpret"].n_rounds
 
 
+def test_replicated_fit_same_through_directory_or_search(blobs,
+                                                         monkeypatch):
+    """The replicated CIVS pass reads its support rows' buckets from the
+    tables' directory; searching them instead (`query_batch` on the same
+    rows, which hashes them and computes their salts itself) must give the
+    same fit. probe = 4 is below the largest bucket, so the salts place
+    the windows."""
+    import repro.core.civs as civs
+    from repro.lsh.pstable import query_batch
+
+    lshp = auto_lsh_params(blobs.points, probe=4)
+    cfg = ALIDConfig(a_cap=48, delta=48, lsh=lshp, seeds_per_round=16,
+                     max_rounds=20)
+    pts = jnp.asarray(blobs.points)
+
+    def searched(tables, idx, salts, probe):
+        return query_batch(tables, pts[jnp.clip(idx, 0, pts.shape[0] - 1)],
+                           lshp)
+
+    engine = make_engine(cfg.spec)
+    direct = fit(blobs.points, cfg, jax.random.PRNGKey(0), engine=engine)
+    assert int(np.asarray(engine.bucket_sizes).max()) > lshp.probe
+    jax.clear_caches()              # retrace CIVS with the searched probe
+    monkeypatch.setattr(civs, "probe_rows", searched)
+    try:
+        search = fit(blobs.points, cfg, jax.random.PRNGKey(0))
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    assert direct.n_clusters > 0 and direct.n_rounds == search.n_rounds
+    for name in ("labels", "densities", "support_idx", "support_w",
+                 "support_v"):
+        np.testing.assert_array_equal(getattr(direct, name),
+                                      getattr(search, name))
+
+
 # ------------------------------------------------------- the claim reducer --
 def test_reducer_exact_tie_prefers_larger_row():
     """Deliberate exact density tie: the point claimed by both rows must go

@@ -3,8 +3,10 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
-from repro.lsh.pstable import LSHParams, bucket_sizes, build_lsh, hash_points, query_batch
+from repro.lsh.pstable import (LSHParams, bucket_sizes, build_lsh, hash_points,
+                               probe_rows, query_batch, query_salts)
 
 
 def _recall(points, queries, truth_sets, params, seed=0):
@@ -70,3 +72,65 @@ def test_probe_window_spreads_within_bucket():
     out = np.asarray(query_batch(tables, jnp.asarray(data[:32]), params))
     distinct = {tuple(row.tolist()) for row in out}
     assert len(distinct) > 4, "probe windows did not spread across the bucket"
+
+
+# (seg_len, probe, n_tables) over 300 points: one bucket per table, larger
+# than the probe, so the salt places every window; buckets of one or two
+# points, smaller than the probe; and a mix of both
+_PROBE_CASES = {"larger_than_probe": (100.0, 4, 2),
+                "smaller_than_probe": (0.7, 12, 3),
+                "mixed": (3.0, 8, 4)}
+
+
+def _tables_for(case, dtype=jnp.float32, n=300):
+    seg_len, probe, n_tables = _PROBE_CASES[case]
+    rng = np.random.default_rng(5)
+    data = jnp.asarray(rng.normal(size=(n, 6)).astype(np.float32)).astype(dtype)
+    params = LSHParams(n_tables=n_tables, n_projections=4, seg_len=seg_len,
+                       probe=probe)
+    return data, params, build_lsh(data, params, jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", list(_PROBE_CASES))
+def test_probe_rows_equals_query_batch_on_data_rows(case, dtype):
+    data, params, tables = _tables_for(case, dtype)
+    n, probe, n_tables = data.shape[0], params.probe, params.n_tables
+    idx = jnp.asarray(np.random.default_rng(6).permutation(n).astype(np.int32))
+    salts = query_salts(data[idx], tables.proj, tables.bias)
+    want = np.asarray(query_batch(tables, data[idx], params))
+    got = np.asarray(probe_rows(tables, idx, salts, probe))
+    np.testing.assert_array_equal(got, want)
+
+    # the cases cover what they are named for, and in each some window's
+    # last member is the table's last sorted slot
+    dirs = np.asarray(tables.directory)[np.asarray(idx)]
+    head, size = dirs[:, :n_tables].T, dirs[:, n_tables:].T       # (L, Q)
+    span = np.maximum(size - probe, 0)
+    offset = np.where(span > 0, np.asarray(salts) % (span + 1), 0)
+    assert (head + offset + np.minimum(size - offset, probe) == n).any()
+    if case == "larger_than_probe":
+        assert (size > probe).all()
+    elif case == "smaller_than_probe":
+        assert (size < probe).all()
+    else:
+        assert (size > probe).any() and (size < probe).any()
+
+
+@pytest.mark.parametrize("case", list(_PROBE_CASES))
+def test_bucket_directory_matches_binary_search(case):
+    data, params, tables = _tables_for(case)
+    n_tables = params.n_tables
+    sk = np.asarray(tables.sorted_keys)
+    perm = np.asarray(tables.perm)
+    dirs = np.asarray(tables.directory)
+    assert dirs.shape == (data.shape[0], 2 * n_tables)
+    for l in range(n_tables):
+        keys = np.empty_like(sk[l])
+        keys[perm[l]] = sk[l]                     # each point's own key
+        left = np.searchsorted(sk[l], keys, "left")
+        right = np.searchsorted(sk[l], keys, "right")
+        np.testing.assert_array_equal(dirs[:, l], left)
+        np.testing.assert_array_equal(dirs[:, n_tables + l], right - left)
+    np.testing.assert_array_equal(np.asarray(bucket_sizes(tables)),
+                                  dirs[:, n_tables])

@@ -9,7 +9,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core.alid import ALIDConfig
+from repro.core.alid import ALIDConfig, EngineSpec
 from repro.core.engine import fit
 from repro.core.online import OnlineStats
 from repro.core.pipeline import PipelineStats
@@ -83,6 +83,25 @@ def test_fit_records_every_round(fits):
             <= s["alid.lane_iters_executed"][0])
     assert s["alid.clusters_accepted"][0] == res.n_clusters
     assert 0 < s["alid.seeds_valid"][0] <= 16 * res.n_rounds
+
+
+@pytest.mark.parametrize("engine,probes", [
+    ("replicated", "lsh.probes_directory"),
+    ("sharded", "lsh.probes_searched"),
+])
+def test_fit_counts_its_probe_path(blobs, forced, engine, probes):
+    """A recorded fit says which LSH probe ran: the replicated tables'
+    directory or the shard tables' binary search, one count per support
+    slot and table of every executed CIVS pass."""
+    spec, cfg = blobs
+    cfg = cfg._replace(spec=EngineSpec(
+        engine=engine, n_shards=3 if engine == "sharded" else 0))
+    fit(spec.points, cfg, jax.random.PRNGKey(0))
+    s = trace.summary()
+    other = ({"lsh.probes_directory", "lsh.probes_searched"} - {probes}).pop()
+    assert other not in s
+    assert s[probes][0] == (s["alid.lane_iters_executed"][0] * cfg.a_cap
+                            * cfg.lsh.n_tables) > 0
 
 
 def test_fit_results_identical_with_recording(fits):
