@@ -591,11 +591,11 @@ class StreamedEngine(_EngineBase):
         a skipped (lane, shard) pair contains no point inside that lane's
         ROI ball, so skipping cannot change the retrieved set."""
         store = self._store
-        b = np.asarray(roi.radius).shape[0]
         if p != 2.0:
-            return np.ones((b, store.n_shards), bool)
-        cen = np.asarray(roi.center, np.float64)          # (B, d)
-        rad = np.asarray(roi.radius, np.float64)          # (B,)
+            return np.ones((roi.radius.shape[0], store.n_shards), bool)
+        with span("alid.stream.wait"):
+            cen = np.asarray(roi.center, np.float64)      # (B, d)
+            rad = np.asarray(roi.radius, np.float64)      # (B,)
         dist = np.sqrt(
             ((cen[:, None, :] - store.centers[None]) ** 2).sum(-1))
         reach = rad[:, None] + store.radii[None]
@@ -616,70 +616,87 @@ class StreamedEngine(_EngineBase):
             lane_np = (~done_np) & (c_np <= cfg.c_outer)
             if not lane_np.any():
                 break
-            new_state = _lid_batch(state, k, cfg)
-            roi = _roi_batch(new_state, k, jnp.asarray(c_np, jnp.int32), cfg)
-            sup_idx, sup_v, sup_x, sup_mask, ovf = _civs_begin_batch(
-                new_state, cfg)
+            with span("alid.stream.iter"):
+                new_state = _lid_batch(state, k, cfg)
+                roi = _roi_batch(new_state, k, jnp.asarray(c_np, jnp.int32),
+                                 cfg)
+                sup_idx, sup_v, sup_x, sup_mask, ovf = _civs_begin_batch(
+                    new_state, cfg)
 
-            keys, salts = _hash_queries_batch(sup_v, store.proj, store.bias,
-                                              cfg.lsh.seg_len, cfg.backend)
-            # frozen lanes' results are discarded by the lane select below,
-            # so don't let their stale ROIs force shard uploads
-            touch = self._route(roi, cfg.p) & lane_np[:, None]
-            routed = np.flatnonzero(touch.any(axis=0))
-            carry = _init_carry_batch(b, cfg.delta, d, cfg.dtype)
-            if routed.size:
-                # global probe windows, carved on host from the host tables
-                # — ROUTED shards only: an untouched shard holds no point
-                # inside any lane's ROI ball, so its bucket members could
-                # never survive the ROI filter; spending the probe budget on
-                # the reachable shards alone keeps min(bucket∩routed, probe)
-                # candidates and skips the S−T unused searchsorted passes
-                keys_np, salts_np = np.asarray(keys), np.asarray(salts)
-                n_tables, q = keys_np.shape[1], keys_np.shape[2]
-                st, lo, hi = shard_bucket_windows_host(
-                    store.sorted_keys[routed],
-                    keys_np.transpose(1, 0, 2).reshape(n_tables, b * q),
-                    salts_np.transpose(1, 0, 2).reshape(n_tables, b * q),
-                    probe)
-                # (T, L, B*q) -> (T, B, L, q)
-                st = st.reshape(-1, n_tables, b, q).transpose(0, 2, 1, 3)
-                lo = lo.reshape(-1, n_tables, b, q).transpose(0, 2, 1, 3)
-                hi = hi.reshape(-1, n_tables, b, q).transpose(0, 2, 1, 3)
+                keys, salts = _hash_queries_batch(
+                    sup_v, store.proj, store.bias, cfg.lsh.seg_len,
+                    cfg.backend)
+                # frozen lanes' results are discarded by the lane select
+                # below, so don't let their stale ROIs force shard uploads
+                with span("alid.stream.route"):
+                    touch = self._route(roi, cfg.p) & lane_np[:, None]
+                    routed = np.flatnonzero(touch.any(axis=0))
+                if trace.recording():
+                    trace.count("alid.stream.shards_routed", routed.size)
+                    trace.count("alid.stream.shards_total", store.n_shards)
+                    trace.count("alid.stream.lane_shard_pairs",
+                                int(touch.sum()))
+                carry = _init_carry_batch(b, cfg.delta, d, cfg.dtype)
+                if routed.size:
+                    # global probe windows, carved on host from the host
+                    # tables — ROUTED shards only: an untouched shard holds no
+                    # point inside any lane's ROI ball, so its bucket members
+                    # could never survive the ROI filter; spending the probe
+                    # budget on the reachable shards alone keeps
+                    # min(bucket∩routed, probe) candidates and skips the S−T
+                    # unused searchsorted passes
+                    with span("alid.stream.windows"):
+                        with span("alid.stream.wait"):
+                            keys_np = np.asarray(keys)
+                            salts_np = np.asarray(salts)
+                        n_tables, q = keys_np.shape[1], keys_np.shape[2]
+                        flat = (n_tables, b * q)
+                        st, lo, hi = shard_bucket_windows_host(
+                            store.sorted_keys[routed],
+                            keys_np.transpose(1, 0, 2).reshape(flat),
+                            salts_np.transpose(1, 0, 2).reshape(flat), probe)
+                        # (T, L, B*q) -> (T, B, L, q)
+                        st, lo, hi = (
+                            a.reshape(-1, n_tables, b, q).transpose(0, 2, 1, 3)
+                            for a in (st, lo, hi))
 
-                # stream the routed shards through the pipeline (prefetched
-                # bundles arrive in routed order, so the carry folds are
-                # identical to the synchronous path)
-                for pos, s, bundle in self._pipeline.stream(routed):
-                    pts_s, sk, pm, gmap = bundle
-                    with span("alid.stream.chunk", self.stats, "compute_s"):
-                        carry = _stream_chunk_batch(
-                            carry, pts_s, sk, pm, gmap, keys,
-                            jnp.asarray(st[pos]), jnp.asarray(lo[pos]),
-                            jnp.asarray(hi[pos]), roi.center, roi.radius,
-                            active, sup_idx, sup_mask,
-                            jnp.asarray(touch[:, s]), probe, cfg.p,
-                            cfg.backend, cfg.dtype)
-                del pts_s, sk, pm, gmap, bundle, st, lo, hi
-            psi_idx, psi_valid, psi_v, n_cand = _finalize_batch(carry)
+                    # stream the routed shards through the pipeline
+                    # (prefetched bundles arrive in routed order, so the
+                    # carry folds are identical to the synchronous path)
+                    for pos, s, bundle in self._pipeline.stream(routed):
+                        pts_s, sk, pm, gmap = bundle
+                        with span("alid.stream.chunk", self.stats,
+                                  "compute_s"):
+                            carry = _stream_chunk_batch(
+                                carry, pts_s, sk, pm, gmap, keys,
+                                jnp.asarray(st[pos]), jnp.asarray(lo[pos]),
+                                jnp.asarray(hi[pos]), roi.center,
+                                roi.radius, active, sup_idx, sup_mask,
+                                jnp.asarray(touch[:, s]), probe, cfg.p,
+                                cfg.backend, cfg.dtype)
+                    del pts_s, sk, pm, gmap, bundle, st, lo, hi
+                psi_idx, psi_valid, psi_v, n_cand = _finalize_batch(carry)
 
-            res = _civs_finish_batch(new_state, sup_idx, sup_v, sup_x,
-                                     sup_mask, psi_idx, psi_valid, psi_v, k,
-                                     n_cand, ovf, cfg)
-            grown = roi.radius >= cfg.stop_frac * roi.r_out
-            new_done = np.asarray(
-                (~res.infective_found) & (grown | (res.n_candidates == 0)))
+                res = _civs_finish_batch(new_state, sup_idx, sup_v, sup_x,
+                                         sup_mask, psi_idx, psi_valid, psi_v,
+                                         k, n_cand, ovf, cfg)
+                grown = roi.radius >= cfg.stop_frac * roi.r_out
+                with span("alid.stream.wait"):
+                    new_done = np.asarray((~res.infective_found)
+                                          & (grown | (res.n_candidates == 0)))
 
-            state = _select_lanes(jnp.asarray(lane_np), res.state, state)
-            overflow_np |= lane_np & np.asarray(res.overflow)
-            done_np = np.where(lane_np, new_done & (c_np > 1), done_np)
-            c_np = np.where(lane_np, c_np + 1, c_np)
-            # drop this iteration's device intermediates NOW — otherwise a
-            # second generation stays live until the next iteration rebinds
-            # the names, doubling the O(cap) working set this engine exists
-            # to bound
-            del new_state, roi, sup_idx, sup_v, sup_x, sup_mask, carry
-            del psi_idx, psi_valid, psi_v, n_cand, res, grown, keys, salts
+                state = _select_lanes(jnp.asarray(lane_np), res.state, state)
+                with span("alid.stream.wait"):
+                    overflow_np |= lane_np & np.asarray(res.overflow)
+                done_np = np.where(lane_np, new_done & (c_np > 1), done_np)
+                c_np = np.where(lane_np, c_np + 1, c_np)
+                # drop this iteration's device intermediates NOW — otherwise
+                # a second generation stays live until the next iteration
+                # rebinds the names, doubling the O(cap) working set this
+                # engine exists to bound
+                del new_state, roi, sup_idx, sup_v, sup_x, sup_mask, carry
+                del psi_idx, psi_valid, psi_v, n_cand, res, grown, keys
+                del salts
 
         state = _lid_batch(state, k, cfg)   # final polish, as alid_from_seed
         return _seed_results_batch(state, jnp.asarray(c_np, jnp.int32),
