@@ -21,8 +21,10 @@ Runtime rule
 streamed-retrace    run a tiny streamed fit TWICE with identical shapes and
                     count jit tracing-cache misses on the second run. The
                     per-round host stages (`engine._lid_batch`,
-                    `_stream_chunk_batch`, ...) are keyed by static config
-                    + shapes only, so the second fit must trace NOTHING; a
+                    `_gather_fold`, ...) are keyed by static config,
+                    shapes and fold sizes only (a fold's size comes from
+                    the host's slot count, `civs.fold_size`, which the same
+                    data repeats), so the second fit must trace NOTHING; a
                     miss means a stage's signature hashes something
                     per-call (exactly the regression this gate exists to
                     catch). Needs jax's internal test_util counter; if the

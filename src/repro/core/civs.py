@@ -24,16 +24,23 @@ Two retrieval engines sit behind the one `civs_update` signature:
     chunk into a running top-delta candidate buffer (`jax.lax.top_k` over
     [buffer ++ chunk]). The per-chunk math is the module-level
     `retrieve_chunk` with an explicit carry (`init_retrieval_carry` /
-    `finalize_retrieval`), which the host-streamed engine
-    (`engine.StreamedEngine`) drives directly — one device_put shard at a
-    time — outside any jit loop. Because shards partition the dataset and share the
-    LSH projections, the union over shards of the chunked retrieval equals
-    the monolithic retrieval exactly when probe covers the buckets (tested
-    in tests/test_sharded.py), and a GLOBAL probe budget
+    `finalize_retrieval`). Because shards partition the dataset and share
+    the LSH projections, the union over shards of the chunked retrieval
+    equals the monolithic retrieval exactly when probe covers the buckets
+    (tested in tests/test_sharded.py), and a GLOBAL probe budget
     (`pstable.shard_bucket_windows`) keeps the per-bucket sample size at
     min(bucket, probe) — the replicated engine's — even when an oversized
     bucket spans many shards. Peak live affinity/candidate state is
     O(shard + a_cap + delta), not O(n).
+
+The host-streamed engine (`engine.StreamedEngine`) does not run
+`retrieve_chunk` shard by shard. It plans each pass on the host
+(`plan_shard_folds`), folds each routed shard's slots of the global probe
+windows into one per-pass candidate buffer (`gather_shard_slots`, one
+device_put shard at a time), and runs the filter and selection once per
+pass (`select_pass`). That tail is `select_candidates`, the same function
+`retrieve_chunk` ends in, so both engines filter, de-duplicate and select
+with one implementation (tests/test_streamed_pass.py).
 """
 
 from __future__ import annotations
@@ -43,6 +50,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.lid import LIDState
 from repro.kernels import ops
@@ -168,21 +176,17 @@ def retrieve_chunk(carry, pts_s, sk, pm, gmap, keys, starts, lo, hi,
                    roi_center, roi_radius, active, sup_idx, sup_slot_mask,
                    probe: int, p: float, backend: str = "auto"):
     """CIVS steps 2-4 for ONE shard/chunk, folded into the running top-delta
-    carry — THE chunk step, shared verbatim by the in-jit sharded engine
-    (`_retrieve_sharded`'s fori_loop slices the store and calls this) and the
-    host-streamed engine (which `device_put`s one shard at a time and calls
-    it through a jitted vmapped wrapper). One implementation means the
-    streamed engine is exact by construction, not by reimplementation.
+    carry — the in-jit sharded engine's chunk step (`_retrieve_sharded`'s
+    fori_loop slices the store and calls this). Its filter and selection
+    are `select_candidates`, which the host-streamed engine runs once per
+    pass over the candidates of every routed shard.
 
     pts_s (cap_s, d) / sk, pm (L, cap_s) / gmap (cap_s,): one shard's points,
     sorted-key tables, and slot->global map. keys/starts/lo/hi (L, a_cap):
     pre-hashed support queries + this shard's slice of the global probe
     windows (`shard_bucket_windows`). Carry as in `init_retrieval_carry`.
     """
-    best_neg, best_idx, best_v, n_cand = carry
-    n = active.shape[0]
     shard_cap = pts_s.shape[0]
-    delta = best_neg.shape[0]
 
     local = probe_tables_window(sk, pm, keys, starts, lo, hi, probe)
     local = jnp.where(sup_slot_mask[:, None], local, -1)
@@ -190,6 +194,24 @@ def retrieve_chunk(carry, pts_s, sk, pm, gmap, keys, starts, lo, hi,
     safe_slot = jnp.clip(flat, 0, shard_cap - 1)
     gidx = jnp.where(flat >= 0, gmap[safe_slot], -1)
     vc = pts_s[safe_slot]
+    return select_candidates(carry, vc, gidx, roi_center, roi_radius, active,
+                             sup_idx, sup_slot_mask, p, backend)
+
+
+def select_candidates(carry, vc, gidx, roi_center, roi_radius, active,
+                      sup_idx, sup_slot_mask, p: float,
+                      backend: str = "auto"):
+    """CIVS steps 3-4 on gathered candidates, folded into the running
+    top-delta carry: drop inactive points and support members, filter to
+    the ROI ball, de-duplicate, and merge the nearest into the carry.
+
+    vc (C, d) / gidx (C,): candidate rows and their global ids, -1 = no
+    candidate. Shared by `retrieve_chunk` and the streamed pass
+    (`select_pass`).
+    """
+    best_neg, best_idx, best_v, n_cand = carry
+    n = active.shape[0]
+    delta = best_neg.shape[0]
 
     safe_g = jnp.clip(gidx, 0, n - 1)
     valid = (gidx >= 0) & active[safe_g]
@@ -231,6 +253,113 @@ def finalize_retrieval(carry):
     psi_idx = jnp.where(psi_valid, best_idx, -1)
     psi_v = jnp.where(psi_valid[:, None], best_v, 0.0)
     return psi_idx, psi_valid, psi_v, n_candidates
+
+
+# ------------------------------------------------ the streamed CIVS pass --
+# The host-streamed engine runs CIVS steps 2-4 as one pass over the routed
+# shards: each shard's fold gathers only the slots of the global probe
+# windows that fall in it into a per-pass buffer, and the filter and
+# selection (`select_candidates`) run once over that buffer.
+
+# smallest static slot count of a fold; the ladder doubles from here
+FOLD_MIN_SLOTS = 1024
+
+
+def fold_size(count: int, full: int) -> int:
+    """Static slot count of a fold that gathers `count` slots: the least
+    power of two from `FOLD_MIN_SLOTS` up that holds them, capped at the
+    pass's `full` width, so a fit compiles about ten fold shapes."""
+    need = 1 << max(int(count) - 1, 0).bit_length()
+    return min(full, max(FOLD_MIN_SLOTS, need))
+
+
+def plan_shard_folds(starts, lo, hi, touch, sup_mask, cap: int, probe: int):
+    """Host plan of a streamed pass's folds, one per routed shard.
+
+    starts/lo/hi (T, L, B, Q): the routed shards' cuts of every lane's
+    global probe windows (`shard_bucket_windows_host`); touch (T, B): the
+    lanes whose ROI ball meets each shard; sup_mask (B, Q): the support
+    slots that query. A shard holds `hi - lo` slots of a (table, lane,
+    query) window, and the shards cut each window into consecutive pieces
+    in routed order, so a slot's place in the window is the widths of the
+    earlier shards' pieces plus its place in this one: unique across
+    shards, so the folds never write one buffer slot twice.
+
+    Returns (plans (T, 3, R) int32, counts (T,)), R = L·B·Q rows. For each
+    row, `plans[t]` holds the slots the fold gathers from it (0 unless the
+    lane touches the shard and the query slot holds a support point), the
+    flat (L·cap) position in the shard's `perm` of its first slot, and the
+    flat (B·M) place of its first slot in the pass buffers, M = Q·L·probe
+    laid out as `retrieve_chunk`'s candidates. counts[t] is the fold's
+    slot count.
+    """
+    n_t, n_l, n_b, n_q = lo.shape
+    flat = (n_t, n_l * n_b * n_q)
+    i32 = np.int32
+    width = hi - lo
+    # the pieces' places in their windows (a loop over the few shards:
+    # numpy's cumsum across the leading axis is several times slower)
+    base = np.zeros_like(width)
+    for t in range(1, n_t):
+        np.add(base[t - 1], width[t - 1], out=base[t])
+    cnt = width * (touch[:, None, :, None] & sup_mask[None, None])
+    table = np.arange(n_l, dtype=i32)[:, None, None]
+    src = table * cap + starts + lo
+    # (lane, query, table) order of the candidates within the buffers
+    lane_query = np.arange(n_b * n_q, dtype=i32).reshape(n_b, n_q)
+    dst = lane_query * (n_l * probe) + table * probe + base
+    plans = np.stack([a.reshape(flat) for a in (cnt, src, dst)], axis=1)
+    return plans.astype(i32, copy=False), cnt.reshape(flat).sum(axis=1)
+
+
+def gather_shard_slots(rows, ids, pts_s, pm, gmap, plan, size: int):
+    """One routed shard's fold of a streamed CIVS pass: read `perm`, the
+    slot->global map and the points at this shard's slots of the pass's
+    probe windows, and write the rows and global ids where the slots sit
+    in the pass buffers.
+
+    rows (B, M, d) / ids (B, M): the pass buffers (-1 = no candidate);
+    pts_s (cap, d) / pm (L, cap) / gmap (cap,): the shard's bundle; plan
+    (3, R): the shard's rows of `plan_shard_folds`. `size` is the fold's
+    static slot count (`fold_size`), at least the plan's; slots past the
+    plan's write nothing. The windows are exact bucket ranges of the
+    shard's sorted keys (`searchsorted`), so the keys are not read.
+    """
+    cnt, src, dst = plan
+    n_rows = cnt.shape[0]
+    first = jnp.cumsum(cnt) - cnt                 # each row's first slot
+    count = first[-1] + cnt[-1]
+    slot = jnp.arange(size, dtype=jnp.int32)
+    # each slot's row: the non-empty rows' first slots scattered (empty
+    # rows out of range, each to its own place), then a running max
+    head = jnp.where(cnt > 0, first, size + jnp.arange(n_rows,
+                                                       dtype=jnp.int32))
+    row = jax.lax.cummax(jnp.zeros((size,), jnp.int32).at[head].set(
+        jnp.arange(n_rows, dtype=jnp.int32), mode="drop",
+        unique_indices=True))
+    at = jnp.stack([src - first, dst - first], axis=-1)[row] + slot[:, None]
+    flat_pm = pm.reshape(-1)
+    local = flat_pm[jnp.clip(at[:, 0], 0, flat_pm.shape[0] - 1)]
+    safe = jnp.clip(local, 0, gmap.shape[0] - 1)
+    gid = jnp.where(local >= 0, gmap[safe], -1)
+    n_b, m, d = rows.shape
+    # slots past the count go out of range, each to its own place, and drop
+    to = jnp.where(slot < count, at[:, 1], n_b * m + slot)
+    ids = ids.reshape(-1).at[to].set(gid, mode="drop", unique_indices=True)
+    rows = rows.reshape(-1, d).at[to].set(pts_s[safe].astype(rows.dtype),
+                                          mode="drop", unique_indices=True)
+    return rows.reshape(n_b, m, d), ids.reshape(n_b, m)
+
+
+def select_pass(rows, ids, roi_center, roi_radius, active, sup_idx,
+                sup_slot_mask, delta: int, p: float, backend: str = "auto"):
+    """One lane's filter and selection over its pass buffer (rows (M, d),
+    ids (M,)), from an empty carry: (psi_idx, psi_valid, psi_v,
+    n_candidates), as `finalize_retrieval` reads them."""
+    carry = init_retrieval_carry(delta, rows.shape[-1], rows.dtype)
+    return finalize_retrieval(select_candidates(
+        carry, rows, ids, roi_center, roi_radius, active, sup_idx,
+        sup_slot_mask, p, backend))
 
 
 # Conservative slack on the ball-intersection routing test: shard radii and

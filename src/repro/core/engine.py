@@ -31,9 +31,9 @@ retrieval substrate lives:
   * StreamedEngine   — the ALID outer loop lifted to HOST level over a
                        host-resident `StreamedStore`: one routed shard is
                        device_put at a time into a double-buffered slot, so
-                       peak device memory is O(shard + cap) for datasets
-                       beyond device (or host-aggregate) HBM (DESIGN.md
-                       §3.3).
+                       peak device memory is O(shard + the pass's candidate
+                       buffer), independent of n, for datasets beyond
+                       device (or host-aggregate) HBM (DESIGN.md §3.3).
 
 All four consume the PRNG stream identically (one split for the LSH build,
 one per round for seeding) and share seeding statistics, so on tie-free data
@@ -55,9 +55,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.alid import (ALIDConfig, Clustering, EngineSpec, SeedResult,
                              _sample_seeds, alid_from_seed, storage_dtype)
 from repro.core.affinity import estimate_k
-from repro.core.civs import (_ROUTE_EPS, compact_support, finalize_retrieval,
-                             init_retrieval_carry, rebuild_support,
-                             retrieve_chunk)
+from repro.core.civs import (_ROUTE_EPS, compact_support, fold_size,
+                             gather_shard_slots, plan_shard_folds,
+                             rebuild_support, select_pass)
 from repro.core.lid import init_state_from, lid_solve
 from repro.core.pipeline import PipelineStats, ShardPipeline
 from repro.core.resilience import DEFAULT_RETRY, RetryPolicy, resilient
@@ -401,41 +401,32 @@ def _hash_queries_batch(sup_v, proj, bias, seg_len: float,
         lambda q: hash_queries(q, proj, bias, seg_len, backend))(sup_v)
 
 
-@functools.partial(jax.jit, static_argnames=("b", "delta", "d", "dtype"))
-def _init_carry_batch(b: int, delta: int, d: int, dtype: str = "float32"):
-    return jax.tree.map(lambda x: jnp.broadcast_to(x, (b,) + x.shape),
-                        init_retrieval_carry(delta, d, storage_dtype(dtype)))
+@functools.partial(jax.jit, static_argnames=("b", "m", "d", "dtype"))
+def _pass_buffers(b: int, m: int, d: int, dtype: str = "float32"):
+    """Empty candidate buffers of one streamed CIVS pass: rows (b, m, d) in
+    the storage dtype and global ids (b, m), -1 = no candidate."""
+    return (jnp.zeros((b, m, d), storage_dtype(dtype)),
+            jnp.full((b, m), -1, jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnames=("probe", "p", "backend",
-                                             "dtype"))
-def _stream_chunk_batch(carry, pts_s, sk, pm, gmap, keys, starts, lo, hi,
-                        center, radius, active, sup_idx, sup_slot_mask,
-                        touch, probe: int, p: float, backend: str = "auto",
-                        dtype: str = "float32"):
-    """One device-resident shard folded into every seed lane's carry.
-
-    The shard leaves (pts_s/sk/pm/gmap) broadcast; everything per-seed maps.
-    `touch` replays the lax.cond-under-vmap select of `_retrieve_sharded`:
-    lanes whose ROI ball misses the shard ball keep their carry untouched.
-    The np.float32 slab holds storage-rounded values, so the astype to the
-    storage dtype is exact (matching ShardedEngine's `store.shards` dtype).
-    """
-    pts_s = pts_s.astype(storage_dtype(dtype))
-
-    def one(carry1, keys1, st1, lo1, hi1, cen1, rad1, sidx1, smask1, t1):
-        new = retrieve_chunk(carry1, pts_s, sk, pm, gmap, keys1, st1, lo1,
-                             hi1, cen1, rad1, active, sidx1, smask1,
-                             probe=probe, p=p, backend=backend)
-        return jax.tree.map(lambda a, b_: jnp.where(t1, a, b_), new, carry1)
-
-    return jax.vmap(one)(carry, keys, starts, lo, hi, center, radius,
-                         sup_idx, sup_slot_mask, touch)
+@functools.partial(jax.jit, static_argnames=("size",), donate_argnums=(0, 1))
+def _gather_fold(rows, ids, pts_s, pm, gmap, plan, size: int):
+    """One routed shard's slots into the pass buffers, which it donates
+    onward (`civs.gather_shard_slots`)."""
+    return gather_shard_slots(rows, ids, pts_s, pm, gmap, plan, size)
 
 
-@jax.jit
-def _finalize_batch(carry):
-    return jax.vmap(finalize_retrieval)(carry)
+@functools.partial(jax.jit, static_argnames=("delta", "p", "backend"))
+def _select_pass_batch(rows, ids, center, radius, active, sup_idx,
+                       sup_slot_mask, delta: int, p: float,
+                       backend: str = "auto"):
+    """The pass's filter and selection, once for every seed lane
+    (`civs.select_pass`): one `roi_filter` launch over the whole
+    (B, a_cap·L·probe) candidate layout."""
+    return jax.vmap(
+        lambda r, i, c, rad, si, sm: select_pass(
+            r, i, c, rad, active, si, sm, delta, p, backend))(
+        rows, ids, center, radius, sup_idx, sup_slot_mask)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -483,16 +474,24 @@ class StreamedEngine(_EngineBase):
     pass's ROUTED shard list ahead of the compute loop, device_put-ing
     bundles into a depth-k slot ring so disk read + H2D upload of shard s+1
     overlap the device compute of shard s. Peak device memory is
-    O((prefetch_depth+1)·shard + cap); peak host memory adds the LRU budget
-    (DESIGN.md §3.3).
+    O((prefetch_depth+1)·shard + seeds·a_cap·L·probe·d), the slot ring and
+    the pass's candidate buffer, independent of n; peak host memory adds
+    the LRU budget (DESIGN.md §3.3).
+
+    A CIVS pass gathers each routed shard's slots of the global probe
+    windows into one per-pass candidate buffer, (seeds, a_cap·L·probe, d)
+    whatever n is, and filters and selects once over it
+    (`civs.select_pass`). It does not run `civs.retrieve_chunk` shard by
+    shard; it shares that step's filter and selection
+    (`civs.select_candidates`) with ShardedEngine.
 
     The PRNG schedule (one split for the store build, one per round for
-    seeding), the seeding statistics (exact global bucket sizes), the chunk
-    math (`civs.retrieve_chunk` — shared with ShardedEngine), and the claim
-    reducer are all identical to the other engines — and the pipeline
-    consumes shards in routed order regardless of arrival — so on tie-free
-    data the streamed engine produces the same labels as the replicated one
-    (pipelined or not) and stays in the parity suite."""
+    seeding), the seeding statistics (exact global bucket sizes), the
+    candidate filter and selection, and the claim reducer are all
+    identical to the other engines — and each shard's fold writes its own
+    slots of the pass buffer, whatever order the shards arrive in — so on
+    tie-free data the streamed engine produces the same labels as the
+    replicated one (pipelined or not) and stays in the parity suite."""
 
     def __init__(self, spec: EngineSpec):
         super().__init__()
@@ -605,6 +604,9 @@ class StreamedEngine(_EngineBase):
         cfg, store, k = self._cfg, self._store, self.k
         b, d = int(seeds.shape[0]), store.dim
         probe = cfg.lsh.probe
+        # candidate slots of one lane's CIVS pass: a_cap queries x L tables
+        # x a probe-wide window
+        width = cfg.a_cap * cfg.lsh.n_tables * probe
 
         state = _init_states_batch(self._seed_rows(seeds), seeds, cfg.cap,
                                    cfg.dtype)
@@ -636,7 +638,7 @@ class StreamedEngine(_EngineBase):
                     trace.count("alid.stream.shards_total", store.n_shards)
                     trace.count("alid.stream.lane_shard_pairs",
                                 int(touch.sum()))
-                carry = _init_carry_batch(b, cfg.delta, d, cfg.dtype)
+                rows, ids = _pass_buffers(b, width, d, cfg.dtype)
                 if routed.size:
                     # global probe windows, carved on host from the host
                     # tables — ROUTED shards only: an untouched shard holds no
@@ -644,38 +646,44 @@ class StreamedEngine(_EngineBase):
                     # could never survive the ROI filter; spending the probe
                     # budget on the reachable shards alone keeps
                     # min(bucket∩routed, probe) candidates and skips the S−T
-                    # unused searchsorted passes
+                    # unused searchsorted passes. The windows' slots are then
+                    # planned shard by shard (`plan_shard_folds`)
                     with span("alid.stream.windows"):
                         with span("alid.stream.wait"):
                             keys_np = np.asarray(keys)
                             salts_np = np.asarray(salts)
+                            sup_mask_np = np.asarray(sup_mask)
                         n_tables, q = keys_np.shape[1], keys_np.shape[2]
                         flat = (n_tables, b * q)
                         st, lo, hi = shard_bucket_windows_host(
                             store.sorted_keys[routed],
                             keys_np.transpose(1, 0, 2).reshape(flat),
                             salts_np.transpose(1, 0, 2).reshape(flat), probe)
-                        # (T, L, B*q) -> (T, B, L, q)
-                        st, lo, hi = (
-                            a.reshape(-1, n_tables, b, q).transpose(0, 2, 1, 3)
-                            for a in (st, lo, hi))
+                        plans, counts = plan_shard_folds(
+                            *(a.reshape(-1, n_tables, b, q)
+                              for a in (st, lo, hi)),
+                            touch[:, routed].T, sup_mask_np,
+                            store.global_idx.shape[1], probe)
+                    if trace.recording():
+                        trace.count("alid.stream.fold_slots",
+                                    int(counts.sum()))
 
-                    # stream the routed shards through the pipeline
-                    # (prefetched bundles arrive in routed order, so the
-                    # carry folds are identical to the synchronous path)
+                    # stream the routed shards through the pipeline; each
+                    # fold writes its own slots of the pass buffers, so the
+                    # order they arrive in cannot change the pass
                     for pos, s, bundle in self._pipeline.stream(routed):
-                        pts_s, sk, pm, gmap = bundle
+                        pts_s, _, pm, gmap = bundle
                         with span("alid.stream.chunk", self.stats,
                                   "compute_s"):
-                            carry = _stream_chunk_batch(
-                                carry, pts_s, sk, pm, gmap, keys,
-                                jnp.asarray(st[pos]), jnp.asarray(lo[pos]),
-                                jnp.asarray(hi[pos]), roi.center,
-                                roi.radius, active, sup_idx, sup_mask,
-                                jnp.asarray(touch[:, s]), probe, cfg.p,
-                                cfg.backend, cfg.dtype)
-                    del pts_s, sk, pm, gmap, bundle, st, lo, hi
-                psi_idx, psi_valid, psi_v, n_cand = _finalize_batch(carry)
+                            if counts[pos]:
+                                rows, ids = _gather_fold(
+                                    rows, ids, pts_s, pm, gmap,
+                                    jnp.asarray(plans[pos]),
+                                    fold_size(counts[pos], b * width))
+                    del pts_s, pm, gmap, bundle, st, lo, hi, plans
+                psi_idx, psi_valid, psi_v, n_cand = _select_pass_batch(
+                    rows, ids, roi.center, roi.radius, active, sup_idx,
+                    sup_mask, cfg.delta, cfg.p, cfg.backend)
 
                 res = _civs_finish_batch(new_state, sup_idx, sup_v, sup_x,
                                          sup_mask, psi_idx, psi_valid, psi_v,
@@ -694,7 +702,7 @@ class StreamedEngine(_EngineBase):
                 # a second generation stays live until the next iteration
                 # rebinds the names, doubling the O(cap) working set this
                 # engine exists to bound
-                del new_state, roi, sup_idx, sup_v, sup_x, sup_mask, carry
+                del new_state, roi, sup_idx, sup_v, sup_x, sup_mask, rows, ids
                 del psi_idx, psi_valid, psi_v, n_cand, res, grown, keys
                 del salts
 

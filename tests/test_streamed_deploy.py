@@ -103,8 +103,9 @@ def test_memmapped_fit_holds_to_the_float64_reference(
 def test_streamed_fit_counts_its_shards(npy, cfg, tmp_path, monkeypatch,
                                         recording):
     """A recorded streamed fit counts, per outer iteration, the shards it
-    routes against the store's shards and the (lane, shard) pairs routing
-    keeps, and records its iterations; unrecorded, nothing moves."""
+    routes against the store's shards, the (lane, shard) pairs routing
+    keeps and the probe-window slots its shard folds gather, and records
+    its iterations; unrecorded, nothing moves."""
     trace.reset()
     if recording:
         monkeypatch.setattr(trace, "recording", lambda: True)
@@ -126,6 +127,9 @@ def test_streamed_fit_counts_its_shards(npy, cfg, tmp_path, monkeypatch,
     assert s["alid.stream.wait"][0] >= 3 * iters
     assert s["alid.stream.wait"][1] <= s["alid.stream.iter"][1]
     assert s["alid.stream.chunk"][0] == routed
+    width = cfg.a_cap * cfg.lsh.n_tables * cfg.lsh.probe
+    assert 0 < s["alid.stream.fold_slots"][0] <= (
+        routed * cfg.seeds_per_round * width)
 
 
 class _NoFetch:
